@@ -87,14 +87,12 @@ def _cost_lowering(arch: str, shape_name: str, k: int, mesh) -> Dict:
         pass  # ssd chunk scan unrolls exactly; keep production chunk size
     fn, args, outs, donate = DR.build_cell(arch, shape_name, mesh,
                                            chunks=(ch, ch), cfg=cfg)
-    from repro.compat import use_mesh
     with unroll_scans():
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(fn, out_shardings=outs,
                               donate_argnums=donate).lower(*args)
             compiled = lowered.compile()
-    from repro.compat import cost_analysis
-    ca = cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     txt = compiled.as_text()
     coll = DR.collective_bytes(txt)
     return {

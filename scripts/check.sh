@@ -3,9 +3,8 @@
 #
 #   make check            # or: scripts/check.sh
 #
-# Runs the ROADMAP tier-1 command (full pytest; ZERO failures required —
-# the seed-era "43 known-failing NN tests" carve-out is gone since the
-# JAX compat shim, repro/compat.py), a 2-size bench_propagation smoke
+# Runs the ROADMAP tier-1 command (full pytest; ZERO failures required),
+# a 2-size bench_propagation smoke
 # comparing all registered propagation backends, a model-zoo solver smoke
 # (every zoo model through the EPS engine, DESIGN.md §10, with per-model
 # typed-propagator-table sizes, §12), a session-API smoke (cold+warm

@@ -495,6 +495,8 @@ class CompiledRunner:
     Compilation is explicit (`fn.lower(...).compile()`) so the session
     can *count* compiles and *time* them — `n_compiles` staying flat
     across a second solve is the warm-path proof the tests assert on.
+    `placement` records where the last call's first output leaf (the
+    lane stores) lives: ``(device, shard shape)`` per addressable shard.
     """
 
     def __init__(self, fn, aot: bool = True):
@@ -504,21 +506,26 @@ class CompiledRunner:
         self.n_compiles = 0
         self.n_calls = 0
         self.compile_s = 0.0
+        self.placement: Tuple[Tuple[str, tuple], ...] = ()
 
     def __call__(self, *args):
         self.n_calls += 1
-        if not self.aot:   # mesh path: plain jit (AOT + shard_map varies
-            return self.fn(*args)   # across jax versions; counters track
-                                    # builds only)
-        key = _aval_key(args)
-        exe = self._execs.get(key)
-        if exe is None:
-            t0 = time.time()
-            exe = self.fn.lower(*args).compile()
-            self.compile_s += time.time() - t0
-            self.n_compiles += 1
-            self._execs[key] = exe
-        return exe(*args)
+        if not self.aot:   # mesh path: plain jit (counters track builds)
+            out = self.fn(*args)
+        else:
+            key = _aval_key(args)
+            exe = self._execs.get(key)
+            if exe is None:
+                t0 = time.time()
+                exe = self.fn.lower(*args).compile()
+                self.compile_s += time.time() - t0
+                self.n_compiles += 1
+                self._execs[key] = exe
+            out = exe(*args)
+        self.placement = tuple(
+            (str(s.device), tuple(s.data.shape))
+            for s in jax.tree.leaves(out)[0].addressable_shards)
+        return out
 
 
 class Solver:
@@ -569,8 +576,7 @@ class Solver:
             state_spec = jax.tree.map(lambda _: spec, state0)
             carry_spec = (state_spec, P(), P(), P(), spec)
             cm_spec = jax.tree.map(lambda _: P(), cm)
-            from repro.compat import shard_map
-            fn = jax.jit(shard_map(
+            fn = jax.jit(jax.shard_map(
                 dev_fn, mesh=cfg.mesh,
                 in_specs=(cm_spec, spec, spec, carry_spec),
                 out_specs=carry_spec, check_vma=False))
@@ -594,6 +600,7 @@ class Solver:
         out["n_runners"] = len(self._runners)
         out["n_compiles"] = sum(r.n_compiles for r in self._runners.values())
         out["compile_s"] = sum(r.compile_s for r in self._runners.values())
+        out["placement"] = [r.placement for r in self._runners.values()]
         return out
 
     def clear_cache(self) -> None:

@@ -17,9 +17,12 @@ benchmarks → examples):
                CPU/GPU/TPU-portable production default;
   ``scatter``  propagator-centric scatter-join oracle — the literal
                reading of the paper's atomic load/store compilation;
-  ``pallas``   the VMEM-resident Pallas TPU kernel
-               (`kernels/fixpoint_kernel.fixpoint_pallas`), interpret-mode
-               on CPU, real `pallas_call` on TPU;
+  ``pallas``   the VMEM-resident Pallas kernel
+               (`kernels/fixpoint_kernel.fixpoint_pallas`), run by the
+               Pallas interpreter: Mosaic does not lower it for a TPU yet,
+               so on a TPU construction raises
+               (`fixpoint_kernel.MOSAIC_REFUSAL`) unless ``interpret=True``
+               is passed;
   ``pallas_resident``
                the resident *search* megakernel (DESIGN.md §13): K whole
                supersteps — dispatch, branch, fixpoint, commit — fused
@@ -106,7 +109,7 @@ def _pallas_batch(cm, lb, ub, dom, lane_tile, max_sweeps, interpret):
 
 
 class PallasBackend:
-    """VMEM-resident Pallas fixpoint kernel (TPU; interpret-mode on CPU).
+    """VMEM-resident Pallas fixpoint kernel (Pallas interpreter only).
 
     `lane_tile` is the grid-cell width — the number of lanes whose two
     stores co-reside in VMEM for the whole loop (the TURBO shared-memory
@@ -127,9 +130,14 @@ class PallasBackend:
                  interpret: Optional[bool] = None,
                  max_sweeps: int = 16384):
         self.lane_tile = lane_tile
-        # default: real pallas_call on TPU, interpreter everywhere else
+        # default: the interpreter off a TPU; on a TPU the kernel would be
+        # lowered by Mosaic, which refuses it — raise rather than fall back
         self.interpret = (jax.default_backend() != "tpu"
                           if interpret is None else interpret)
+        if not self.interpret:
+            from repro.kernels.fixpoint_kernel import MOSAIC_REFUSAL
+            raise NotImplementedError(f"backend {self.name!r}: "
+                                      f"{MOSAIC_REFUSAL}")
         self.max_sweeps = max_sweeps
 
     def fixpoint(self, cm, lb, ub, *, max_iters=None):
@@ -183,7 +191,8 @@ class PallasResidentBackend(PallasBackend):
         tile = (n_lanes if self.resident_lane_tile in (0, None)
                 else self.resident_lane_tile)
         tile = fit_lane_tile(cm, tile, n_lanes, resident=True,
-                             max_depth=max_depth, pool_size=pool_size)
+                             max_depth=max_depth, pool_size=pool_size,
+                             interpret=self.interpret)
         return -(n_lanes // -tile)
 
     def superstep_launch(self, cm: CompiledModel, subs_lb, subs_ub, st,

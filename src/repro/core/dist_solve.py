@@ -59,7 +59,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import eps
 from repro.core import search as S
 from repro.core.api import (CompiledRunner, Improvement, Progress,
@@ -226,10 +225,10 @@ def _build_runner(session, cm: CompiledModel, cfg: SolveConfig,
     cm_spec = jax.tree.map(lambda _: P(), cm)
     dev_fn = partial(_run_chunk, opts, cfg.stop_on_first, cfg.chunk,
                      (AXIS,))
-    fn = jax.jit(shard_map(dev_fn, mesh=mesh,
-                           in_specs=(cm_spec, pool_spec, pool_spec,
-                                     carry_spec),
-                           out_specs=carry_spec, check_vma=False))
+    fn = jax.jit(jax.shard_map(dev_fn, mesh=mesh,
+                               in_specs=(cm_spec, pool_spec, pool_spec,
+                                         carry_spec),
+                               out_specs=carry_spec, check_vma=False))
     runner = CompiledRunner(fn, aot=False)
     session._runners[key] = runner
     session.stats["runner_builds"] += 1

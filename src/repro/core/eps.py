@@ -37,14 +37,20 @@ def decompose(cm: CompiledModel, target: int,
     if (lb > ub).any():
         return lb[None], ub[None]          # failed root: one failed sub
 
+    bv = np.asarray(cm.branch_vars)
+
+    def width(l, u):
+        return int((u - l)[bv].clip(min=0).sum())
+
     frontier: List[Tuple[np.ndarray, np.ndarray]] = [(lb, ub)]
+    widths = [width(lb, ub)]             # kept parallel to `frontier`
     leaves: List[Tuple[np.ndarray, np.ndarray]] = []
 
-    bv = np.asarray(cm.branch_vars)
     while frontier and len(frontier) + len(leaves) < target:
-        # widest subproblem first keeps the pool balanced
-        widths = [int((u - l)[bv].clip(min=0).sum()) for l, u in frontier]
-        i = int(np.argmax(widths))
+        # widest subproblem first (the earliest on ties) keeps the pool
+        # balanced
+        i = max(range(len(widths)), key=widths.__getitem__)
+        widths.pop(i)
         l, u = frontier.pop(i)
         unf = l[bv] < u[bv]
         if not unf.any():
@@ -69,6 +75,7 @@ def decompose(cm: CompiledModel, target: int,
             nlb, nub = np.asarray(nlb), np.asarray(nub)
             if not (nlb > nub).any():
                 frontier.append((nlb, nub))
+                widths.append(width(nlb, nub))
 
     pool = frontier + leaves
     if not pool:                            # everything failed: UNSAT root

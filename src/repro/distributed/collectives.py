@@ -95,8 +95,7 @@ def pod_sync_grads(grads: Dict, mesh, axis: str = "pod",
     P_ = jax.sharding.PartitionSpec
 
     def sync_leaf(g, spec):
-        from repro.compat import shard_map
-        fn = shard_map(
+        fn = jax.shard_map(
             partial(op, axis_name=axis),
             mesh=mesh, in_specs=spec, out_specs=spec,
             check_vma=False)

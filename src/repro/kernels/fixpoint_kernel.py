@@ -34,12 +34,10 @@ dominant sweep intermediates — and `fixpoint_pallas`/`search_pallas`
 auto-shrink their lane tile (with a warning) instead of dying in a
 Mosaic OOM.
 
-Validated in interpret mode on CPU (this container has no TPU); the ops
-used (take/gather along axis 0, elementwise, while_loop/fori_loop/cond)
-lower on TPU Pallas for int32.  The one TPU caveat: the decision-path
-scatter in `search.apply_path_tile` lowers through
-`lax.scatter_min/max`, which Mosaic supports only via serialization —
-acceptable because it touches [L, MD] elements, not [L, V] stores.
+Both kernels run only in the Pallas interpreter today: Mosaic, the TPU
+Pallas compiler, refuses them (`MOSAIC_REFUSAL`; ROADMAP A2 lists every
+refusal in the order the compiler reports them), so ``interpret=False``
+raises before lowering instead of failing deep inside it.
 """
 
 from __future__ import annotations
@@ -69,6 +67,23 @@ N_TABLES = 35        # positional args of fixpoint.sweep_tile, in order
 # model_tables positions the search kernel reads back out (§17 banks)
 _I_DOM_OFF, _I_DOM_TRACK = 31, 32
 _BOOL_FIELDS = ("dec_flip", "fresh", "done", "incomplete", "has_sol")
+
+# What Mosaic reports when the kernels are compiled for a TPU v5e
+# (JAX 0.9.0).  It stops at the first refusal; the next ones are found by
+# avoiding each in turn (ROADMAP A2).
+MOSAIC_REFUSAL = (
+    "the Pallas kernels do not lower on a TPU: Mosaic first refuses the "
+    "rank-1 per-lane blocks (`lane1d` in fixpoint_pallas, `cell1` in "
+    "search_pallas), which are neither the whole array nor a multiple of "
+    "128 lanes; with those avoided it refuses the gather "
+    "`jnp.take(lb, vidx, axis=1)` at core/fixpoint.py:84 (\"Shape "
+    "mismatch in input, indices and output\"). Use backend='gather' on a "
+    "TPU, or interpret=True for the Pallas interpreter.")
+
+
+def _require_interpret(interpret: bool) -> None:
+    if not interpret:
+        raise NotImplementedError(MOSAIC_REFUSAL)
 
 
 def _nbytes(a) -> int:
@@ -152,11 +167,14 @@ def vmem_budget(cm, lane_tile: int, *, resident: bool = False,
 
 def fit_lane_tile(cm, lane_tile: int, n_lanes: int, *,
                   resident: bool = False, max_depth: int = 0,
-                  pool_size: int = 0, limit_bytes: int = None) -> int:
+                  pool_size: int = 0, limit_bytes: int = None,
+                  interpret: bool = True) -> int:
     """Clamp `lane_tile` to `n_lanes` and halve it until the
     `vmem_budget` fits `limit_bytes` (default `VMEM_LIMIT_BYTES`,
     warning on each shrink); raise a clear error when even a single
-    lane per cell does not fit."""
+    lane per cell does not fit.  A compiled kernel (``interpret=False``)
+    raises instead of shrinking: the TPU's tiling refuses the 4-, 2- and
+    1-lane blocks that halving produces."""
     if limit_bytes is None:
         limit_bytes = VMEM_LIMIT_BYTES
     kernel = "search_pallas" if resident else "fixpoint_pallas"
@@ -166,6 +184,13 @@ def fit_lane_tile(cm, lane_tile: int, n_lanes: int, *,
                         pool_size=pool_size)
         if b["total"] <= limit_bytes:
             return tile
+        if not interpret:
+            raise ValueError(
+                f"{kernel}: lane_tile={tile} needs "
+                f"{b['total'] / 2**20:.1f} MB of VMEM (> "
+                f"{limit_bytes / 2**20:.1f} MB) and a compiled kernel "
+                f"cannot shrink it: pass a smaller lane_tile that the "
+                f"TPU's tiling accepts, or use the gather backend")
         if tile == 1:
             raise ValueError(
                 f"{kernel}: model {cm.name or '<unnamed>'} does not fit "
@@ -261,9 +286,10 @@ def fixpoint_pallas(cm, lb, ub, dom=None, *, lane_tile: int = 8,
     §17) it rides in VMEM next to the interval stores and the return
     gains dom' before the counters.
     """
+    _require_interpret(interpret)
     from repro.core.fixpoint import model_tables
     L, V = lb.shape
-    lane_tile = fit_lane_tile(cm, lane_tile, L)
+    lane_tile = fit_lane_tile(cm, lane_tile, L, interpret=interpret)
     pad = (-L) % lane_tile
     if pad:
         lb = jnp.concatenate([lb, jnp.broadcast_to(lb[-1:], (pad, V))])
@@ -470,6 +496,7 @@ def search_pallas(cm, subs_lb, subs_ub, st: S.LaneState, gbest, it,
     under `stop_on_first`) — the host chunk scheduler ORs it into
     `gdone` and stops relaunching.
     """
+    _require_interpret(interpret)
     L, V = st.lb.shape
     MD = st.dec_var.shape[1]
     Spool = subs_lb.shape[0]
@@ -477,7 +504,7 @@ def search_pallas(cm, subs_lb, subs_ub, st: S.LaneState, gbest, it,
 
     tile = L if lane_tile in (0, None) else lane_tile
     tile = fit_lane_tile(cm, tile, L, resident=True, max_depth=MD,
-                         pool_size=Spool)
+                         pool_size=Spool, interpret=interpret)
     pad = (-L) % tile
     if pad:
         st = _pad_lanes(st, pad, dt)

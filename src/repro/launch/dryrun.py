@@ -169,8 +169,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool = False,
     t0 = time.time()
     fn, args, out_shardings, donate = build_cell(arch, shape_name, mesh,
                                                  chunks)
-    from repro.compat import use_mesh
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(fn, out_shardings=out_shardings,
                          donate_argnums=donate)
         lowered = jitted.lower(*args)
@@ -179,8 +178,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool = False,
         compiled = lowered.compile()
         t_compile = time.time() - t0
     ma = compiled.memory_analysis()
-    from repro.compat import cost_analysis
-    ca = cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     txt = compiled.as_text()
     coll = collective_bytes(txt)
     rec = {

@@ -20,7 +20,8 @@ from __future__ import annotations
 import argparse
 import json
 
-from repro.core.api import SolveConfig, Solver
+from repro.core.api import SolveConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve.loadgen import (poisson_trace, run_open_loop,
                                  sequential_reference)
 from repro.serve.scheduler import SolverScheduler
@@ -55,6 +56,7 @@ def main():
                     help="also dump the metrics summary to this file")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = build_config(args)
     trace = poisson_trace(args.requests, args.rate, seed=args.seed)
     sched = SolverScheduler(cfg, max_batch=args.max_batch)

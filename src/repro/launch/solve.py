@@ -84,6 +84,8 @@ def main():
 
     from repro import solver
     from repro.core.models import rcpsp
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.fast:
         warnings.warn("--fast is deprecated; use --preset fast",
@@ -134,12 +136,11 @@ def main():
         carry_spec = (state_spec, P(), P(), P(), spec)
         dev_fn = lambda sl, su, c: _run_chunk(   # noqa: E731
             opts, False, 64, axes, cm, sl, su, c)
-        from repro.compat import shard_map, use_mesh
-        f = jax.jit(shard_map(dev_fn, mesh=mesh,
-                              in_specs=(spec, spec, carry_spec),
-                              out_specs=carry_spec, check_vma=False))
+        f = jax.jit(jax.shard_map(dev_fn, mesh=mesh,
+                                  in_specs=(spec, spec, carry_spec),
+                                  out_specs=carry_spec, check_vma=False))
         t0 = time.time()
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = f.lower(
                 jax.ShapeDtypeStruct((Spool, V), cm.jdtype,
                                      sharding=jax.NamedSharding(mesh, spec)),

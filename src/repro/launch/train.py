@@ -49,8 +49,7 @@ def build(arch: str, smoke: bool, seq: int, global_batch: int,
 
     def one_step(params, opt_state, step):
         batch = {k: jnp.asarray(v) for k, v in data.batch(step).items()}
-        from repro.compat import use_mesh
-        ctx = use_mesh(mesh) if mesh is not None else _null()
+        ctx = jax.set_mesh(mesh) if mesh is not None else _null()
         with ctx:
             return step_jit(params, opt_state, batch)
 

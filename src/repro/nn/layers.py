@@ -102,9 +102,8 @@ def embed_lookup(table, tokens):
 def constrain(x, *axes):
     """with_sharding_constraint by mesh-axis name; silently skipped when
     the named axes aren't in the ambient mesh (smoke tests, 1-device)."""
-    from repro.compat import get_abstract_mesh
-    mesh = get_abstract_mesh()
-    if mesh is None or not mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names:
         return x
     U = jax.sharding.PartitionSpec.UNCONSTRAINED
     entries = []

@@ -159,6 +159,15 @@ def test_registry_roundtrip_and_unknown():
         del B._REGISTRY["probe"]
 
 
+@pytest.mark.parametrize("name", ["pallas", "pallas_resident"])
+def test_pallas_backends_refuse_compiled_kernels(name):
+    """Without the interpreter the kernels would go to Mosaic, which
+    refuses them: construction says so and names the refused gather,
+    instead of failing inside lowering or falling back to gather."""
+    with pytest.raises(NotImplementedError, match=r"fixpoint\.py:84"):
+        get_backend(name, interpret=False)
+
+
 def test_engine_solves_with_every_backend():
     """solve_session(..., opts=SearchOptions(backend=...)) end-to-end on
     CPU for all three backends, identical optimum and node counts (the

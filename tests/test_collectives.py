@@ -22,20 +22,19 @@ def test_int8_psum_mean_accuracy_and_int8_wire():
     out = _run(r"""
 import jax, jax.numpy as jnp, numpy as np
 from functools import partial
-from repro.compat import make_mesh, shard_map
 from repro.distributed.collectives import int8_psum_mean, psum_mean
 
-mesh = make_mesh((8,), ("pod",))
+mesh = jax.make_mesh((8,), ("pod",))
 x = jax.random.normal(jax.random.PRNGKey(0), (8, 64, 32)) * 0.01
 
-f = jax.jit(shard_map(partial(int8_psum_mean, axis_name="pod"),
-                      mesh=mesh,
-                      in_specs=jax.sharding.PartitionSpec("pod"),
-                      out_specs=jax.sharding.PartitionSpec("pod")))
-g = jax.jit(shard_map(partial(psum_mean, axis_name="pod"),
-                      mesh=mesh,
-                      in_specs=jax.sharding.PartitionSpec("pod"),
-                      out_specs=jax.sharding.PartitionSpec("pod")))
+f = jax.jit(jax.shard_map(partial(int8_psum_mean, axis_name="pod"),
+                          mesh=mesh,
+                          in_specs=jax.sharding.PartitionSpec("pod"),
+                          out_specs=jax.sharding.PartitionSpec("pod")))
+g = jax.jit(jax.shard_map(partial(psum_mean, axis_name="pod"),
+                          mesh=mesh,
+                          in_specs=jax.sharding.PartitionSpec("pod"),
+                          out_specs=jax.sharding.PartitionSpec("pod")))
 approx = np.asarray(f(x))
 exact = np.asarray(g(x))
 # error bound: quantization step = max|x|/127; after averaging unchanged
@@ -54,17 +53,16 @@ print("INT8_OK", err, step)
 def test_pod_sync_grads_tree():
     out = _run(r"""
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import make_mesh
 from repro.distributed.collectives import pod_sync_grads
 
-mesh = make_mesh((2, 4), ("pod", "data"))
+mesh = jax.make_mesh((2, 4), ("pod", "data"))
 grads = {"a/w": jnp.ones((4, 4)) * 2.0, "b/w": -jnp.ones((3,))}
 out = pod_sync_grads(grads, mesh, axis="pod", compress=True)
 for k in grads:
     np.testing.assert_allclose(np.asarray(out[k]), np.asarray(grads[k]),
                                atol=0.05)
 # no 'pod' axis in mesh -> no-op
-mesh2 = make_mesh((8,), ("data",))
+mesh2 = jax.make_mesh((8,), ("data",))
 out2 = pod_sync_grads(grads, mesh2, axis="pod")
 assert out2 is grads
 print("POD_SYNC_OK")

@@ -69,8 +69,7 @@ def test_mesh_shards_config_validation():
         solver.SolveConfig(mesh_shards=2, backend="pallas_resident")
     with pytest.raises(ValueError, match="mutually exclusive"):
         import jax
-        from repro.compat import make_mesh
-        mesh = make_mesh((1,), ("lanes",))
+        mesh = jax.make_mesh((1,), ("lanes",))
         solver.SolveConfig(mesh=mesh, lane_axes=("lanes",), mesh_shards=2)
 
 

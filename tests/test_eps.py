@@ -5,8 +5,10 @@ fewer-supersteps vs single-root search."""
 import itertools
 
 import numpy as np
+import pytest
 
 from repro.core import baseline, engine, eps, search as S
+from repro.core.fixpoint import fixpoint
 from util import solve_session
 from repro.core.model import Model
 from repro.core.models import rcpsp
@@ -85,6 +87,48 @@ def test_decompose_hits_target_region():
     for target in (4, 16):
         subs_lb, _ = eps.decompose(cm, target)
         assert subs_lb.shape[0] >= target
+
+
+def _decompose_reference(cm, target, opts):
+    """The split loop written plainly: recompute every frontier width on
+    every step, split the widest (the earliest on ties)."""
+    lb, ub, _, _ = fixpoint(cm, cm.lb0, cm.ub0)
+    frontier, leaves = [(np.asarray(lb), np.asarray(ub))], []
+    bv = np.asarray(cm.branch_vars)
+    while frontier and len(frontier) + len(leaves) < target:
+        widths = [int((u - l)[bv].clip(min=0).sum()) for l, u in frontier]
+        l, u = frontier.pop(int(np.argmax(widths)))
+        unf = l[bv] < u[bv]
+        if not unf.any():
+            leaves.append((l, u))
+            continue
+        key = {S.MIN_DOM: u[bv] - l[bv], S.MIN_LB: l[bv]}.get(opts.var_strategy)
+        v = int(bv[int(np.argmax(unf) if key is None else
+                       np.argmin(np.where(unf, key, np.iinfo(l.dtype).max)))])
+        m = int(l[v]) if opts.val_strategy == S.VAL_MIN \
+            else int((l[v] + u[v]) // 2)
+        left_u, right_l = u.copy(), l.copy()
+        left_u[v], right_l[v] = min(u[v], m), max(l[v], m + 1)
+        for cl, cu in ((l, left_u), (right_l, u)):
+            nl, nu, _, _ = fixpoint(cm, cl, cu)
+            nl, nu = np.asarray(nl), np.asarray(nu)
+            if not (nl > nu).any():
+                frontier.append((nl, nu))
+    pool = frontier + leaves
+    return np.stack([p[0] for p in pool]), np.stack([p[1] for p in pool])
+
+
+@pytest.mark.parametrize("strategy", [S.INPUT_ORDER, S.MIN_LB, S.MIN_DOM])
+def test_decompose_matches_plain_split_loop(strategy):
+    """The pool is exactly the one the plain widest-first loop builds."""
+    inst = rcpsp.generate(6, n_resources=2, seed=3, edge_prob=0.25)
+    cm = rcpsp.build_model(inst)[0].compile()
+    opts = S.SearchOptions(var_strategy=strategy, val_strategy=S.VAL_SPLIT)
+    for target in (5, 24):
+        got = eps.decompose(cm, target, opts)
+        want = _decompose_reference(cm, target, opts)
+        np.testing.assert_array_equal(got[0], want[0])
+        np.testing.assert_array_equal(got[1], want[1])
 
 
 def test_eps_target_same_optimum_fewer_supersteps():

@@ -155,8 +155,7 @@ specs = MD.param_specs(cfg)
 rules = SH.rules_for("train")
 
 def mk(n):
-    from repro.compat import make_mesh
-    return make_mesh((n,), ("data",))
+    return jax.make_mesh((n,), ("data",))
 
 mesh8, mesh4 = mk(8), mk(4)
 from repro.nn.layers import init_params

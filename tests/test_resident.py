@@ -193,6 +193,19 @@ def test_fit_lane_tile_clamps_and_shrinks():
         assert FK.fit_lane_tile(cm, 8, 8, limit_bytes=lim) == 4
 
 
+def test_fit_lane_tile_compiled_kernel_refuses_to_shrink():
+    """A compiled kernel raises where the interpreter would halve the
+    tile: the TPU's tiling refuses 4-, 2- and 1-lane blocks."""
+    cm = _cm()
+    lim = (FK.vmem_budget(cm, 4)["total"]
+           + FK.vmem_budget(cm, 8)["total"]) // 2
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        with pytest.raises(ValueError, match="cannot shrink"):
+            FK.fit_lane_tile(cm, 8, 8, limit_bytes=lim, interpret=False)
+    assert FK.fit_lane_tile(cm, 8, 8, interpret=False) == 8
+
+
 def test_fit_lane_tile_clear_error_when_nothing_fits():
     cm = _cm()
     with warnings.catch_warnings():

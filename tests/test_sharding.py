@@ -9,9 +9,8 @@ from repro import configs
 from repro.distributed import sharding as SH
 from repro.nn import model as MD
 
-# AbstractMesh takes a tuple of (axis_name, size) pairs in this JAX version
-MESH1 = AbstractMesh((("data", 16), ("model", 16)))
-MESH2 = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+MESH1 = AbstractMesh((16, 16), ("data", "model"))
+MESH2 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def test_spec_divisibility_drops_axis():

@@ -147,8 +147,7 @@ def test_multi_device_engine_matches_single():
     import jax
     if jax.device_count() < 4:
         pytest.skip("needs >=4 devices (run under dryrun XLA flags)")
-    from repro.compat import make_mesh
-    mesh = make_mesh((4,), ("workers",))
+    mesh = jax.make_mesh((4,), ("workers",))
     inst = rcpsp.generate(5, n_resources=2, seed=1, edge_prob=0.3)
     m, _ = rcpsp.build_model(inst)
     cm = m.compile()
