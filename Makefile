@@ -6,7 +6,8 @@ export PYTHONPATH
 # tier-1 suite + propagation smoke + model-zoo solver smoke + session-API
 # smoke (cold/warm + solve_many) + solver-serving bench (open-loop
 # continuous batching, §15) + scale bench (sparse banks, §16) + docs
-# check (writes BENCH_propagation_smoke.json; see scripts/check.sh)
+# check (writes the git-ignored BENCH_propagation_smoke.json; see
+# scripts/check.sh)
 check:
 	scripts/check.sh
 

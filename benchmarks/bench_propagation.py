@@ -12,13 +12,11 @@ vectorizes, which is what TURBO exploits on real parallel hardware.
       [--json BENCH_propagation.json]
 
 CSV columns: backend,n_tasks,lanes,ms_per_fixpoint,ms_per_lane,
-sweeps_exec,props_per_sec.  `sweeps_exec` is the backend-reported number
-of sweeps physically executed (pallas runs whole lane *tiles* in
-lockstep, so it exceeds the per-lane counts of the XLA backends on the
-same input).  `props_per_sec` is therefore computed from a
-backend-independent work measure — the gather backend's per-lane useful
-sweep count on the identical stores — so rates are comparable across
-backends: same numerator, each backend's own wall clock.
+sweeps_exec.  `sweeps_exec` is the backend-reported number of sweeps
+physically executed (pallas runs whole lane *tiles* in lockstep, so it
+exceeds the per-lane counts of the XLA backends on the same input).
+These are CPU timings of the backends against each other; what the
+solver costs on the chip is measured by `perfbench` (PERF.md).
 """
 
 from __future__ import annotations
@@ -79,7 +77,7 @@ def main(argv=None):
 
     rng = np.random.default_rng(0)
     header = ("backend,n_tasks,lanes,ms_per_fixpoint,ms_per_lane,"
-              "sweeps_exec,props_per_sec")
+              "sweeps_exec")
     rows = [header]
     records = []
     for n_tasks in args.sizes:
@@ -88,22 +86,15 @@ def main(argv=None):
         cm = m.compile()
         for L in args.lanes:
             lbs, ubs = perturbed_stores(cm, L, rng)
-            # backend-independent work measure: useful per-lane sweeps of
-            # the canonical gather fixpoint on these exact stores
-            useful = int(np.asarray(
-                get_backend("gather").fixpoint_batch(cm, lbs, ubs)[2]).sum())
             for name in backends:
                 kw = dict(lane_tile=min(8, L)) if name == "pallas" else {}
                 dt, sweeps = bench(cm, lbs, ubs, name, **kw)
-                pps = cm.n_props * useful / dt
                 rows.append(f"{name},{n_tasks},{L},{dt * 1e3:.2f},"
-                            f"{dt * 1e3 / L:.3f},{sweeps},{pps:.3g}")
+                            f"{dt * 1e3 / L:.3f},{sweeps}")
                 records.append(dict(backend=name, n_tasks=n_tasks, lanes=L,
                                     ms_per_fixpoint=dt * 1e3,
                                     ms_per_lane=dt * 1e3 / L,
-                                    sweeps_exec=sweeps,
-                                    sweeps_useful=useful,
-                                    props_per_sec=pps))
+                                    sweeps_exec=sweeps))
     print("\n".join(rows))
     if args.json:
         with open(args.json, "w") as fh:

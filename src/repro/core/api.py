@@ -42,6 +42,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.core.compile import CompiledModel
 from repro.core import eps
 from repro.core import search as S
@@ -79,6 +80,19 @@ class SolveResult:
     # within one quantum collapse into a single trace entry whose
     # `superstep` is the quantum's end.
     improvements: Tuple[Improvement, ...] = ()
+    # lockstep cost of the sweeps: the superstep fixpoints' loop rounds
+    # summed (the slowest lane's sweeps each superstep), over the lanes
+    # searched; 1 - n_sweeps / (n_lanes * n_sweep_rounds) is the share of
+    # lane-sweeps spent on lanes that had already converged
+    n_sweep_rounds: int = 0
+    n_lanes: int = 0
+    # host phases of a `Solver.solve_iter` solve (None where not timed):
+    # seconds preparing the pool (EPS decomposition, padding, transfer)
+    # and the decomposition's fixpoint dispatches (0 for a given pool);
+    # seconds in chunk-runner calls, each until its result is ready
+    decompose_s: Optional[float] = None
+    n_decompose_dispatches: Optional[int] = None
+    search_s: Optional[float] = None
 
     @property
     def nodes_per_sec(self) -> float:
@@ -370,6 +384,16 @@ def _chunk_body(opts: S.SearchOptions, stop_on_first: bool, axis_names,
     return st, gbest, gdone, it, pool_head
 
 
+def _chunk_runner(opts: S.SearchOptions, stop_on_first: bool, chunk: int,
+                  axis_names):
+    """`_run_chunk` bound to its statics, named so its executable reads
+    ``jit_run_chunk`` in a device trace (a bare partial reads
+    ``jit__unknown``)."""
+    fn = partial(_run_chunk, opts, stop_on_first, chunk, axis_names)
+    fn.__name__ = "run_chunk"
+    return fn
+
+
 def _run_chunk(opts: S.SearchOptions, stop_on_first: bool, chunk: int,
                axis_names, cm: CompiledModel, subs_lb, subs_ub, carry):
     """One scheduler quantum — the unit of jit compilation and of host
@@ -435,7 +459,8 @@ def derive_result(cm: CompiledModel, best_obj, has_sol, best_sol,
                   incomplete, done: bool, n_nodes: int, n_fails: int,
                   n_sols: int, n_sweeps: int, n_supersteps: int,
                   wall_s: float,
-                  improvements: Tuple[Improvement, ...] = ()
+                  improvements: Tuple[Improvement, ...] = (), *,
+                  n_sweep_rounds: int = 0, n_lanes: int = 0
                   ) -> SolveResult:
     """Derive (status, objective, solution) from terminal lane state.
 
@@ -475,7 +500,9 @@ def derive_result(cm: CompiledModel, best_obj, has_sol, best_sol,
                        n_sols=int(n_sols), n_sweeps=int(n_sweeps),
                        n_supersteps=int(n_supersteps), wall_s=wall_s,
                        complete=complete,
-                       improvements=tuple(improvements))
+                       improvements=tuple(improvements),
+                       n_sweep_rounds=int(n_sweep_rounds),
+                       n_lanes=int(n_lanes))
 
 
 # --------------------------------------------------------------------------
@@ -569,8 +596,7 @@ class Solver:
         opts = cfg.search_options()
         if cfg.mesh is not None:
             axes = cfg.lane_axes
-            dev_fn = partial(_run_chunk, opts, cfg.stop_on_first, cfg.chunk,
-                             axes)
+            dev_fn = _chunk_runner(opts, cfg.stop_on_first, cfg.chunk, axes)
             spec = P(axes)
             state0 = S.init_lanes(cm, cfg.n_lanes * self._n_dev(cfg), opts)
             state_spec = jax.tree.map(lambda _: spec, state0)
@@ -582,7 +608,7 @@ class Solver:
                 out_specs=carry_spec, check_vma=False))
             runner = CompiledRunner(fn, aot=False)
         else:
-            fn = partial(_run_chunk, opts, cfg.stop_on_first, cfg.chunk, ())
+            fn = _chunk_runner(opts, cfg.stop_on_first, cfg.chunk, ())
             if batched:
                 fn = jax.vmap(fn)
             runner = CompiledRunner(jax.jit(fn), aot=True)
@@ -614,21 +640,25 @@ class Solver:
     # -- pool preparation -------------------------------------------------
 
     def _pool_for(self, cm: CompiledModel, cfg: SolveConfig,
-                  subs: Optional[tuple], opts: S.SearchOptions):
-        if subs is None:
-            subs_lb, subs_ub = eps.decompose(cm, cfg.resolved_eps_target(),
-                                             opts)
-        else:
-            subs_lb, subs_ub = subs
-        subs_lb, subs_ub = np.asarray(subs_lb), np.asarray(subs_ub)
-        size = subs_lb.shape[0]
-        if cfg.pad_pool:
-            size = _bucket(size)
-        if cfg.mesh is not None:
-            n_dev = self._n_dev(cfg)
-            size = size + (-size) % n_dev
-        subs_lb, subs_ub = eps.pad_pool(subs_lb, subs_ub, size)
-        return jnp.asarray(subs_lb), jnp.asarray(subs_ub)
+                  subs: Optional[tuple], opts: S.SearchOptions,
+                  stats: Optional[dict] = None):
+        """The padded pool on the device.  ``stats`` is handed to
+        `eps.decompose` when the pool is decomposed here."""
+        with obs.span("repro.solve.pool"):
+            if subs is None:
+                subs_lb, subs_ub = eps.decompose(
+                    cm, cfg.resolved_eps_target(), opts, stats)
+            else:
+                subs_lb, subs_ub = subs
+            subs_lb, subs_ub = np.asarray(subs_lb), np.asarray(subs_ub)
+            size = subs_lb.shape[0]
+            if cfg.pad_pool:
+                size = _bucket(size)
+            if cfg.mesh is not None:
+                n_dev = self._n_dev(cfg)
+                size = size + (-size) % n_dev
+            subs_lb, subs_ub = eps.pad_pool(subs_lb, subs_ub, size)
+            return jnp.asarray(subs_lb), jnp.asarray(subs_ub)
 
     # -- solve / solve_iter ----------------------------------------------
 
@@ -652,17 +682,25 @@ class Solver:
         (``final=True``) carries the `SolveResult` (with its
         `improvements` trace)."""
         cfg = self._config_for(config, overrides)
-        if cfg.mesh_shards is not None:
-            from repro.core import dist_solve
-            self.stats["solves"] += 1
-            yield from dist_solve.solve_iter_dist(self, _canonical(cm), cfg,
-                                                  subs=subs)
-            return
+        with obs.solve():
+            if cfg.mesh_shards is not None:
+                from repro.core import dist_solve
+                self.stats["solves"] += 1
+                yield from dist_solve.solve_iter_dist(
+                    self, _canonical(cm), cfg, subs=subs)
+            else:
+                yield from self._solve_iter(cm, cfg, subs)
+
+    def _solve_iter(self, cm: CompiledModel, cfg: SolveConfig,
+                    subs: Optional[tuple]) -> Iterator[Progress]:
         opts = cfg.search_options()
         t0 = time.time()
         self.stats["solves"] += 1
         cm = _canonical(cm)
-        subs_lb, subs_ub = self._pool_for(cm, cfg, subs, opts)
+        pool_stats: Dict[str, int] = {}
+        t_pool = time.perf_counter()
+        subs_lb, subs_ub = self._pool_for(cm, cfg, subs, opts, pool_stats)
+        decompose_s = time.perf_counter() - t_pool
 
         builds0 = self.stats["runner_builds"]
         runner = self._runner_for(cm, cfg, batched=False)
@@ -681,31 +719,36 @@ class Solver:
         dt = cm.jdtype
         big = int(np.iinfo(dt).max // 4)
         best_seen = big
+        search_s = 0.0
         while True:
-            carry = jax.block_until_ready(runner(cm, subs_lb, subs_ub,
-                                                 carry))
+            t_chunk = time.perf_counter()
+            with obs.span("repro.solve.chunk"):
+                carry = jax.block_until_ready(runner(cm, subs_lb, subs_ub,
+                                                     carry))
+            search_s += time.perf_counter() - t_chunk
             if self.stats["last_solve_cold"] is None:
                 self.stats["last_solve_cold"] = (
                     runner.n_compiles > compiles0
                     or self.stats["runner_builds"] > builds0)
-            st, gbest, gdone, it, _ = carry
-            wall = time.time() - t0
-            superstep = int(np.asarray(it).max())
-            n_nodes = int(np.asarray(st.n_nodes).sum())
-            n_sols = int(np.asarray(st.n_sols).sum())
-            has = bool(np.asarray(st.has_sol).any())
-            obj = None
-            incumbent = None
-            if cm.obj_var >= 0 and has:
-                flat = np.asarray(st.best_obj).reshape(-1)
-                i = int(flat.argmin())
-                obj = int(flat[i])
-                if obj < best_seen:
-                    best_seen = obj
-                    improvements.append(Improvement(superstep, wall, obj))
-                    incumbent = np.asarray(st.best_sol).reshape(
-                        -1, cm.n_vars)[i]
-            stop = bool(np.asarray(gdone).all())
+            with obs.span("repro.solve.poll"):
+                st, gbest, gdone, it, _ = carry
+                wall = time.time() - t0
+                superstep = int(np.asarray(it).max())
+                n_nodes = int(np.asarray(st.n_nodes).sum())
+                n_sols = int(np.asarray(st.n_sols).sum())
+                has = bool(np.asarray(st.has_sol).any())
+                obj = None
+                incumbent = None
+                if cm.obj_var >= 0 and has:
+                    flat = np.asarray(st.best_obj).reshape(-1)
+                    i = int(flat.argmin())
+                    obj = int(flat[i])
+                    if obj < best_seen:
+                        best_seen = obj
+                        improvements.append(Improvement(superstep, wall, obj))
+                        incumbent = np.asarray(st.best_sol).reshape(
+                            -1, cm.n_vars)[i]
+                stop = bool(np.asarray(gdone).all())
             if cfg.timeout_s is not None and wall > cfg.timeout_s:
                 stop = True
             if (cfg.max_supersteps is not None
@@ -717,15 +760,22 @@ class Solver:
                                n_nodes=n_nodes, n_sols=n_sols, wall_s=wall,
                                t_host=t0 + wall)
                 continue
-            totals = S.lane_totals(st)
-            # exhaustion, not gdone: a stop_on_first early-out sets gdone
-            # without draining the pool and must not claim OPTIMAL/UNSAT
-            exhausted = bool(np.asarray(st.done).all())
-            res = derive_result(
-                cm, st.best_obj, st.has_sol, st.best_sol, st.incomplete,
-                exhausted, totals["n_nodes"],
-                totals["n_fails"], totals["n_sols"], totals["n_sweeps"],
-                superstep, time.time() - t0, tuple(improvements))
+            with obs.span("repro.solve.poll"):
+                totals = S.lane_totals(st)
+                # exhaustion, not gdone: a stop_on_first early-out sets
+                # gdone without draining the pool and must not claim
+                # OPTIMAL/UNSAT
+                exhausted = bool(np.asarray(st.done).all())
+                res = derive_result(
+                    cm, st.best_obj, st.has_sol, st.best_sol, st.incomplete,
+                    exhausted, totals["n_nodes"],
+                    totals["n_fails"], totals["n_sols"], totals["n_sweeps"],
+                    superstep, time.time() - t0, tuple(improvements),
+                    n_sweep_rounds=totals["n_sweep_rounds"],
+                    n_lanes=totals["n_lanes"])
+                res = dataclasses.replace(
+                    res, decompose_s=decompose_s, search_s=search_s,
+                    n_decompose_dispatches=pool_stats.get("dispatches", 0))
             yield Progress(superstep=superstep, best_objective=res.objective,
                            has_solution=has, incumbent=res.solution,
                            n_nodes=res.n_nodes, n_sols=res.n_sols,
@@ -977,7 +1027,9 @@ class LaneBatch:
             self._cms[i], sti.best_obj, sti.has_sol, sti.best_sol,
             sti.incomplete, exhausted, totals["n_nodes"],
             totals["n_fails"], totals["n_sols"], totals["n_sweeps"],
-            superstep, wall_s, tuple(improvements))
+            superstep, wall_s, tuple(improvements),
+            n_sweep_rounds=totals["n_sweep_rounds"],
+            n_lanes=totals["n_lanes"])
         self._freeze(i)
         self.request_ids[i] = _IDLE
         self._cms[i] = None
@@ -1001,10 +1053,13 @@ class LaneBatch:
         """Run ONE scheduler quantum (up to ``cfg.chunk`` supersteps per
         live slot; one K-superstep launch under ``pallas_resident``) over
         the whole batch and return the host-visible snapshot."""
-        self.carry = jax.block_until_ready(
-            self.runner(self.cm_b, self.subs_lb, self.subs_ub, self.carry))
+        with obs.span("repro.solve.chunk"):
+            self.carry = jax.block_until_ready(
+                self.runner(self.cm_b, self.subs_lb, self.subs_ub,
+                            self.carry))
         self._host_st = None
-        return self.snapshot()
+        with obs.span("repro.solve.poll"):
+            return self.snapshot()
 
     def snapshot(self) -> BatchSnapshot:
         st, _, gdone, it, _ = self.carry

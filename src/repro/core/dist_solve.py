@@ -51,7 +51,6 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from functools import partial
 from typing import Dict, Iterator, List, Optional, Tuple
 
 import jax
@@ -62,8 +61,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core import eps
 from repro.core import search as S
 from repro.core.api import (CompiledRunner, Improvement, Progress,
-                            SolveConfig, SolveResult, _bucket, _init_carry,
-                            _run_chunk, derive_result, shape_signature)
+                            SolveConfig, SolveResult, _bucket, _chunk_runner,
+                            _init_carry, derive_result, shape_signature)
 from repro.core.compile import CompiledModel
 from repro.distributed import planner
 from repro.distributed.sharding import SOLVE_RULES, dist_solve_specs
@@ -223,8 +222,7 @@ def _build_runner(session, cm: CompiledModel, cfg: SolveConfig,
     opts = cfg.search_options()
     pool_spec, carry_spec = dist_solve_specs(state0, n_pool, mesh)
     cm_spec = jax.tree.map(lambda _: P(), cm)
-    dev_fn = partial(_run_chunk, opts, cfg.stop_on_first, cfg.chunk,
-                     (AXIS,))
+    dev_fn = _chunk_runner(opts, cfg.stop_on_first, cfg.chunk, (AXIS,))
     fn = jax.jit(jax.shard_map(dev_fn, mesh=mesh,
                                in_specs=(cm_spec, pool_spec, pool_spec,
                                          carry_spec),
@@ -479,7 +477,9 @@ def solve_iter_dist(session, cm: CompiledModel, cfg: SolveConfig, *,
             cm, best_obj, has_sol, best_sol, st_h.incomplete,
             exhausted, totals["n_nodes"], totals["n_fails"],
             totals["n_sols"], totals["n_sweeps"], superstep,
-            time.time() - t0, tuple(improvements))
+            time.time() - t0, tuple(improvements),
+            n_sweep_rounds=totals["n_sweep_rounds"],
+            n_lanes=totals["n_lanes"])
         yield Progress(superstep=superstep, best_objective=res.objective,
                        has_solution=has, incumbent=res.solution,
                        n_nodes=res.n_nodes, n_sols=res.n_sols,

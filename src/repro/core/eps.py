@@ -15,25 +15,46 @@ remainder every superstep (DESIGN.md §9).
 
 from __future__ import annotations
 
-from typing import List, Tuple
+from typing import List, Optional, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core.compile import CompiledModel
 from repro.core.fixpoint import fixpoint
 from repro.core import search as S
 
 
+def _propagate(cm: CompiledModel, lb, ub,
+               stats: dict) -> Tuple[np.ndarray, np.ndarray]:
+    """One fixpoint dispatch of the decomposition and its read-back,
+    counted in ``stats["dispatches"]``."""
+    stats["dispatches"] += 1
+    with obs.span("repro.eps.dispatch"):
+        nlb, nub, _, _ = fixpoint(cm, lb, ub)
+        return np.asarray(nlb), np.asarray(nub)
+
+
 def decompose(cm: CompiledModel, target: int,
-              opts: "S.SearchOptions" = None) -> Tuple[np.ndarray, np.ndarray]:
+              opts: "S.SearchOptions" = None,
+              stats: Optional[dict] = None
+              ) -> Tuple[np.ndarray, np.ndarray]:
     """Split the root into ~`target` consistent subproblems.
 
     Returns (subs_lb, subs_ub) with shape [S, V], S ≥ 1 (S can exceed or
     fall short of `target` when the tree is shallow/unsatisfiable).
+    ``stats``, when given, receives ``dispatches``: the number of
+    fixpoint dispatches made (the root and every child).
     """
-    opts = opts or S.SearchOptions()
-    lb, ub, _, _ = fixpoint(cm, cm.lb0, cm.ub0)
-    lb, ub = np.asarray(lb), np.asarray(ub)
+    with obs.span("repro.eps.decompose"):
+        return _decompose(cm, target, opts or S.SearchOptions(),
+                          stats if stats is not None else {})
+
+
+def _decompose(cm: CompiledModel, target: int, opts: "S.SearchOptions",
+               stats: dict) -> Tuple[np.ndarray, np.ndarray]:
+    stats["dispatches"] = 0
+    lb, ub = _propagate(cm, cm.lb0, cm.ub0, stats)
     if (lb > ub).any():
         return lb[None], ub[None]          # failed root: one failed sub
 
@@ -71,8 +92,7 @@ def decompose(cm: CompiledModel, target: int,
                 cu[v] = min(cu[v], m)
             else:
                 cl[v] = max(cl[v], m + 1)
-            nlb, nub, _, _ = fixpoint(cm, cl, cu)
-            nlb, nub = np.asarray(nlb), np.asarray(nub)
+            nlb, nub = _propagate(cm, cl, cu, stats)
             if not (nlb > nub).any():
                 frontier.append((nlb, nub))
                 widths.append(width(nlb, nub))

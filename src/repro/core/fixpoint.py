@@ -597,46 +597,53 @@ def sweep_tile(lb, ub, vidx, coef, rhs, bidx, occ_prop, occ_slot,
     2-tuple comes back unchanged in shape.
     """
     L = lb.shape[0]
-    cand_lb, cand_ub = candidates_tile(lb, ub, vidx, coef, rhs, bidx)
-    # fold the reif-entailment slot in: occ_slot ∈ [0, K] indexes [K+1]
-    g_lb, g_ub = _gather_join(cand_lb, cand_ub, occ_prop, occ_slot, L)
+    # one named scope per kind tile, over its candidates and its join,
+    # so a device trace can attribute op time to the tile
+    with jax.named_scope("tile.linear"):
+        cand_lb, cand_ub = candidates_tile(lb, ub, vidx, coef, rhs, bidx)
+        # fold the reif-entailment slot in: occ_slot ∈ [0, K] indexes [K+1]
+        g_lb, g_ub = _gather_join(cand_lb, cand_ub, occ_prop, occ_slot, L)
     if n_alldiff:
-        if ad_layout == "sparse":
-            ad_lb, ad_ub = alldiff_candidates_sparse_tile(
-                lb, ub, ad_pk_var, ad_pk_off, ad_pk_seg, n_alldiff)
-            occ = jnp.take(ad_ptr, ad_occ_inst) + ad_occ_pos   # flat [V, Dad]
-            j_lb, j_ub = _gather_join_flat(ad_lb, ad_ub, occ, L)
-        else:
-            ad_lb, ad_ub = alldiff_candidates_tile(lb, ub, ad_vars, ad_offs,
-                                                   ad_mask)
-            j_lb, j_ub = _gather_join(ad_lb, ad_ub, ad_occ_inst, ad_occ_pos,
-                                      L)
+        with jax.named_scope("tile.alldiff"):
+            if ad_layout == "sparse":
+                ad_lb, ad_ub = alldiff_candidates_sparse_tile(
+                    lb, ub, ad_pk_var, ad_pk_off, ad_pk_seg, n_alldiff)
+                occ = jnp.take(ad_ptr, ad_occ_inst) + ad_occ_pos  # [V, Dad]
+                j_lb, j_ub = _gather_join_flat(ad_lb, ad_ub, occ, L)
+            else:
+                ad_lb, ad_ub = alldiff_candidates_tile(lb, ub, ad_vars,
+                                                       ad_offs, ad_mask)
+                j_lb, j_ub = _gather_join(ad_lb, ad_ub, ad_occ_inst,
+                                          ad_occ_pos, L)
         g_lb = jnp.maximum(g_lb, j_lb)
         g_ub = jnp.minimum(g_ub, j_ub)
     if n_cumulative:
-        if cu_layout == "sparse":
-            cu_lb, cu_ub = cumulative_candidates_sparse_tile(
-                lb, ub, cu_pk_svar, cu_pk_dur, cu_pk_dem, cu_pk_seg,
-                cu_cap, n_cumulative)
-            occ = jnp.take(cu_ptr, cu_occ_inst) + cu_occ_pos   # flat [V, Dcu]
-            j_lb, j_ub = _gather_join_flat(cu_lb, cu_ub, occ, L)
-        else:
-            cu_lb, cu_ub = cumulative_candidates_tile(
-                lb, ub, cu_svar, cu_dur, cu_dem, cu_cap, horizon)
-            j_lb, j_ub = _gather_join(cu_lb, cu_ub, cu_occ_inst, cu_occ_pos,
-                                      L)
+        with jax.named_scope("tile.cumulative"):
+            if cu_layout == "sparse":
+                cu_lb, cu_ub = cumulative_candidates_sparse_tile(
+                    lb, ub, cu_pk_svar, cu_pk_dur, cu_pk_dem, cu_pk_seg,
+                    cu_cap, n_cumulative)
+                occ = jnp.take(cu_ptr, cu_occ_inst) + cu_occ_pos  # [V, Dcu]
+                j_lb, j_ub = _gather_join_flat(cu_lb, cu_ub, occ, L)
+            else:
+                cu_lb, cu_ub = cumulative_candidates_tile(
+                    lb, ub, cu_svar, cu_dur, cu_dem, cu_cap, horizon)
+                j_lb, j_ub = _gather_join(cu_lb, cu_ub, cu_occ_inst,
+                                          cu_occ_pos, L)
         g_lb = jnp.maximum(g_lb, j_lb)
         g_ub = jnp.minimum(g_ub, j_ub)
     if n_table:
-        d_in = dom if dom is not None else B.from_bounds(
-            lb, ub, dom_off, n_words, track=dom_track)
-        ct_lb, ct_ub, ct_dm = ct_candidates_tile(
-            lb, ub, d_in, ct_vars, ct_mask, ct_supp, dom_off, n_table)
-        j_lb, j_ub = _gather_join(ct_lb, ct_ub, ct_occ_inst, ct_occ_pos, L)
+        with jax.named_scope("tile.table"):
+            d_in = dom if dom is not None else B.from_bounds(
+                lb, ub, dom_off, n_words, track=dom_track)
+            ct_lb, ct_ub, ct_dm = ct_candidates_tile(
+                lb, ub, d_in, ct_vars, ct_mask, ct_supp, dom_off, n_table)
+            j_lb, j_ub = _gather_join(ct_lb, ct_ub, ct_occ_inst, ct_occ_pos,
+                                      L)
+            if dom is not None:
+                dom = _gather_join_dom(ct_dm, ct_occ_inst, ct_occ_pos, dom)
         g_lb = jnp.maximum(g_lb, j_lb)
         g_ub = jnp.minimum(g_ub, j_ub)
-        if dom is not None:
-            dom = _gather_join_dom(ct_dm, ct_occ_inst, ct_occ_pos, dom)
     # clamp candidates into the initial box (overflow guard; sound because
     # box_lo-1/box_hi+1 still cross the opposite bound on failure)
     g_ub = jnp.maximum(g_ub, box_lo[None, :])

@@ -113,6 +113,10 @@ class LaneState(NamedTuple):
     n_fails: jax.Array       # i64
     n_sols: jax.Array        # i64
     n_sweeps: jax.Array      # i64
+    # lockstep sweep rounds: each superstep adds the slowest lane's
+    # sweeps (the batched fixpoint loop's iteration count), the same to
+    # every lane of the batch
+    n_sweep_rounds: jax.Array  # i32
     # bitset domain stores (DESIGN.md §17) — None unless the model has
     # tables or the value strategy is middle_out (None is an empty pytree
     # leaf set, so inactive states keep the legacy carry structure)
@@ -150,7 +154,7 @@ def init_lanes(cm: CompiledModel, n_lanes: int, opts: SearchOptions) -> LaneStat
         best_sol=jnp.zeros((n_lanes, V), dt),
         has_sol=jnp.zeros((n_lanes,), bool),
         n_nodes=z(n_lanes), n_fails=z(n_lanes), n_sols=z(n_lanes),
-        n_sweeps=z(n_lanes),
+        n_sweeps=z(n_lanes), n_sweep_rounds=z(n_lanes),
     )
 
 
@@ -404,6 +408,7 @@ def lane_commit_tile(st: LaneState, pre: LanePrep, lb, ub, sweeps,
     n_fails = st.n_fails + failed.astype(jnp.int32)
     n_sols = st.n_sols + solved.astype(jnp.int32)
     n_sweeps = st.n_sweeps + jnp.asarray(sweeps, jnp.int32)
+    n_sweep_rounds = st.n_sweep_rounds + jnp.max(sweeps).astype(jnp.int32)
 
     # -- 3. record incumbent ------------------------------------------------
     if obj_var >= 0:
@@ -488,7 +493,8 @@ def lane_commit_tile(st: LaneState, pre: LanePrep, lb, ub, sweeps,
         depth=new_depth, next_sub=next_sub, fresh=fresh, done=done,
         incomplete=incomplete, best_obj=best_obj, best_sol=best_sol,
         has_sol=has_sol, n_nodes=n_nodes, n_fails=n_fails, n_sols=n_sols,
-        n_sweeps=n_sweeps, dom=new_dom, root_dom=root_dom)
+        n_sweeps=n_sweeps, n_sweep_rounds=n_sweep_rounds, dom=new_dom,
+        root_dom=root_dom)
 
 
 def lanes_step(cm: CompiledModel, subs_lb, subs_ub, opts: SearchOptions,
@@ -505,25 +511,29 @@ def lanes_step(cm: CompiledModel, subs_lb, subs_ub, opts: SearchOptions,
     `pool_head` is the device-local cursor into the EPS pool; the updated
     cursor is returned alongside the new lane state.
     """
-    st, pool_head = dispatch_pool(st, pool_head, subs_lb.shape[0])
-    pre = lane_load_tile(subs_lb, subs_ub, st, gbest, obj_var=cm.obj_var,
-                         dom_off=cm.dom_off, dom_track=cm.dom_track,
-                         n_words=cm.n_words)
+    with jax.named_scope("superstep.dispatch_pool"):
+        st, pool_head = dispatch_pool(st, pool_head, subs_lb.shape[0])
+    with jax.named_scope("superstep.lane_load"):
+        pre = lane_load_tile(subs_lb, subs_ub, st, gbest,
+                             obj_var=cm.obj_var, dom_off=cm.dom_off,
+                             dom_track=cm.dom_track, n_words=cm.n_words)
     backend = get_backend(opts.backend, **dict(opts.backend_opts))
-    if pre.dom is not None:
-        lb, ub, dom, sweeps, converged = backend.fixpoint_batch(
-            cm, pre.lb, pre.ub, dom=pre.dom,
-            max_iters=opts.max_fixpoint_iters)
-    else:
-        dom = None
-        lb, ub, sweeps, converged = backend.fixpoint_batch(
-            cm, pre.lb, pre.ub, max_iters=opts.max_fixpoint_iters)
-    st = lane_commit_tile(st, pre, lb, ub, sweeps, converged,
-                          cm.branch_vars, obj_var=cm.obj_var,
-                          var_strategy=opts.var_strategy,
-                          val_strategy=opts.val_strategy,
-                          dom=dom, dom_off=cm.dom_off,
-                          dom_track=cm.dom_track)
+    with jax.named_scope("superstep.fixpoint"):
+        if pre.dom is not None:
+            lb, ub, dom, sweeps, converged = backend.fixpoint_batch(
+                cm, pre.lb, pre.ub, dom=pre.dom,
+                max_iters=opts.max_fixpoint_iters)
+        else:
+            dom = None
+            lb, ub, sweeps, converged = backend.fixpoint_batch(
+                cm, pre.lb, pre.ub, max_iters=opts.max_fixpoint_iters)
+    with jax.named_scope("superstep.lane_commit"):
+        st = lane_commit_tile(st, pre, lb, ub, sweeps, converged,
+                              cm.branch_vars, obj_var=cm.obj_var,
+                              var_strategy=opts.var_strategy,
+                              val_strategy=opts.val_strategy,
+                              dom=dom, dom_off=cm.dom_off,
+                              dom_track=cm.dom_track)
     return st, pool_head
 
 
@@ -539,8 +549,14 @@ def all_done(st: LaneState) -> jax.Array:
 def lane_totals(st: LaneState) -> dict:
     """Cross-lane counter totals, as host ints — the stats block every
     terminal `SolveResult` is assembled from (api.derive_result).  Works
-    on device lane states and on host-side (numpy) slices alike."""
+    on device lane states and on host-side (numpy) slices alike.
+    ``n_sweep_rounds`` is the largest lane's (every lane of one batched
+    fixpoint holds the same; lanes split over devices or kernel tiles
+    count their own rounds), ``n_lanes`` the lanes counted."""
+    rounds = np.asarray(st.n_sweep_rounds)
     return dict(n_nodes=int(np.asarray(st.n_nodes).sum()),
                 n_fails=int(np.asarray(st.n_fails).sum()),
                 n_sols=int(np.asarray(st.n_sols).sum()),
-                n_sweeps=int(np.asarray(st.n_sweeps).sum()))
+                n_sweeps=int(np.asarray(st.n_sweeps).sum()),
+                n_sweep_rounds=int(rounds.max(initial=0)),
+                n_lanes=int(rounds.size))
