@@ -87,11 +87,15 @@ class SolveResult:
     n_sweep_rounds: int = 0
     n_lanes: int = 0
     # host phases of a `Solver.solve_iter` solve (None where not timed):
-    # seconds preparing the pool (EPS decomposition, padding, transfer)
-    # and the decomposition's fixpoint dispatches (0 for a given pool);
-    # seconds in chunk-runner calls, each until its result is ready
+    # seconds preparing the pool (EPS decomposition, padding, transfer);
+    # the decomposition's device calls (the root's fixpoint and the split
+    # loop), splits and the lockstep sweep rounds of its pair fixpoints
+    # (0 for a given pool); seconds in chunk-runner calls, each until its
+    # result is ready
     decompose_s: Optional[float] = None
     n_decompose_dispatches: Optional[int] = None
+    n_decompose_splits: Optional[int] = None
+    n_decompose_sweep_rounds: Optional[int] = None
     search_s: Optional[float] = None
 
     @property
@@ -515,40 +519,61 @@ def _aval_key(args) -> tuple:
     return (treedef, tuple(shaped_abstractify(x) for x in leaves))
 
 
-class CompiledRunner:
-    """One cache slot: a jitted chunk runner plus its AOT-compiled
-    executables keyed by argument avals (pool-size buckets land here).
+class CompiledProgram:
+    """A jitted function plus its AOT-compiled executables keyed by
+    argument avals.
 
     Compilation is explicit (`fn.lower(...).compile()`) so the session
     can *count* compiles and *time* them — `n_compiles` staying flat
     across a second solve is the warm-path proof the tests assert on.
-    `placement` records where the last call's first output leaf (the
-    lane stores) lives: ``(device, shard shape)`` per addressable shard.
+    The EPS split loop (`Solver._decomposer_for`) is one; the chunk
+    runners are the `CompiledRunner` subclass, so a wrapper of runner
+    calls sees search only.
     """
 
-    def __init__(self, fn, aot: bool = True):
+    def __init__(self, fn):
         self.fn = fn
-        self.aot = aot
         self._execs: Dict[tuple, Any] = {}
         self.n_compiles = 0
         self.n_calls = 0
         self.compile_s = 0.0
-        self.placement: Tuple[Tuple[str, tuple], ...] = ()
+
+    def compile(self, *args):
+        """The executable for ``args``' avals, lowered and compiled on
+        first sight."""
+        key = _aval_key(args)
+        exe = self._execs.get(key)
+        if exe is None:
+            t0 = time.time()
+            exe = self.fn.lower(*args).compile()
+            self.compile_s += time.time() - t0
+            self.n_compiles += 1
+            self._execs[key] = exe
+        return exe
 
     def __call__(self, *args):
         self.n_calls += 1
-        if not self.aot:   # mesh path: plain jit (counters track builds)
+        return self.compile(*args)(*args)
+
+
+class CompiledRunner(CompiledProgram):
+    """One cache slot: a jitted chunk runner (pool-size buckets land in
+    its executables).  `placement` records where the last call's first
+    output leaf (the lane stores) lives: ``(device, shard shape)`` per
+    addressable shard.
+    """
+
+    def __init__(self, fn, aot: bool = True):
+        super().__init__(fn)
+        self.aot = aot
+        self.placement: Tuple[Tuple[str, tuple], ...] = ()
+
+    def __call__(self, *args):
+        if self.aot:
+            out = super().__call__(*args)
+        else:   # mesh path: plain jit (counters track builds)
+            self.n_calls += 1
             out = self.fn(*args)
-        else:
-            key = _aval_key(args)
-            exe = self._execs.get(key)
-            if exe is None:
-                t0 = time.time()
-                exe = self.fn.lower(*args).compile()
-                self.compile_s += time.time() - t0
-                self.n_compiles += 1
-                self._execs[key] = exe
-            out = exe(*args)
         self.placement = tuple(
             (str(s.device), tuple(s.data.shape))
             for s in jax.tree.leaves(out)[0].addressable_shards)
@@ -574,6 +599,7 @@ class Solver:
         base = config if config is not None else SolveConfig.preset("prove")
         self.config = base.replace(**overrides) if overrides else base
         self._runners: Dict[tuple, CompiledRunner] = {}
+        self._decomposers: Dict[tuple, CompiledProgram] = {}
         self.stats: Dict[str, Any] = {
             "solves": 0, "runner_builds": 0, "runner_hits": 0,
             "last_solve_cold": None,
@@ -588,6 +614,7 @@ class Solver:
 
     def _runner_for(self, cm: CompiledModel, cfg: SolveConfig,
                     batched: bool) -> CompiledRunner:
+        self._decomposer_for(cm, cfg)
         key = (shape_signature(cm), cfg.compile_key(), batched)
         runner = self._runners.get(key)
         if runner is not None:
@@ -616,38 +643,69 @@ class Solver:
         self.stats["runner_builds"] += 1
         return runner
 
+    def _decomposer_for(self, cm: CompiledModel,
+                        cfg: SolveConfig) -> CompiledProgram:
+        """The compiled EPS split loop (`eps.split_program`) for ``cm``'s
+        shapes and the config's target and branching rule.  Every chunk
+        runner lookup (`_runner_for`) builds it, so a solve given its
+        pool leaves the decomposition of that shape compiled too."""
+        target = cfg.resolved_eps_target()
+        key = (shape_signature(cm), target, cfg.var_strategy,
+               cfg.val_strategy)
+        prog = self._decomposers.get(key)
+        if prog is None:
+            prog = CompiledProgram(jax.jit(eps.split_program(
+                target, cfg.var_strategy, cfg.val_strategy)))
+            prog.compile(cm, cm.lb0, cm.ub0)
+            self._decomposers[key] = prog
+        return prog
+
+    def decompose(self, cm: CompiledModel, *,
+                  config: Optional[SolveConfig] = None,
+                  stats: Optional[dict] = None):
+        """``cm``'s EPS pool (`eps.decompose`) under the config's target
+        and branching rule, on the session's compiled split loop."""
+        cfg = config if config is not None else self.config
+        cm = _canonical(cm)
+        return eps.decompose(cm, cfg.resolved_eps_target(),
+                             cfg.search_options(), stats,
+                             program=self._decomposer_for(cm, cfg))
+
     @staticmethod
     def _n_dev(cfg: SolveConfig) -> int:
         return int(np.prod([cfg.mesh.shape[a] for a in cfg.lane_axes]))
 
     def session_stats(self) -> Dict[str, Any]:
-        """Aggregate cache/compile counters across all cached runners."""
+        """Aggregate cache/compile counters across all cached runners
+        and decomposition programs."""
+        progs = [*self._runners.values(), *self._decomposers.values()]
         out = dict(self.stats)
         out["n_runners"] = len(self._runners)
-        out["n_compiles"] = sum(r.n_compiles for r in self._runners.values())
-        out["compile_s"] = sum(r.compile_s for r in self._runners.values())
+        out["n_decomposers"] = len(self._decomposers)
+        out["n_compiles"] = sum(r.n_compiles for r in progs)
+        out["compile_s"] = sum(r.compile_s for r in progs)
         out["placement"] = [r.placement for r in self._runners.values()]
         return out
 
     def clear_cache(self) -> None:
-        """Drop every cached runner and compiled executable.  The cache
-        is otherwise unbounded (one executable per shape-signature ×
-        compile-key × pool-bucket) — long-lived serving processes that
-        churn through many distinct model shapes should evict
-        periodically; counters are kept."""
+        """Drop every cached runner, decomposition program and compiled
+        executable.  The cache is otherwise unbounded (one executable
+        per shape-signature × compile-key × pool-bucket) — long-lived
+        serving processes that churn through many distinct model shapes
+        should evict periodically; counters are kept."""
         self._runners.clear()
+        self._decomposers.clear()
 
     # -- pool preparation -------------------------------------------------
 
     def _pool_for(self, cm: CompiledModel, cfg: SolveConfig,
-                  subs: Optional[tuple], opts: S.SearchOptions,
-                  stats: Optional[dict] = None):
+                  subs: Optional[tuple], stats: Optional[dict] = None):
         """The padded pool on the device.  ``stats`` is handed to
         `eps.decompose` when the pool is decomposed here."""
         with obs.span("repro.solve.pool"):
             if subs is None:
-                subs_lb, subs_ub = eps.decompose(
-                    cm, cfg.resolved_eps_target(), opts, stats)
+                subs_lb, subs_ub = self.decompose(cm, config=cfg,
+                                                  stats=stats)
             else:
                 subs_lb, subs_ub = subs
             subs_lb, subs_ub = np.asarray(subs_lb), np.asarray(subs_ub)
@@ -699,7 +757,7 @@ class Solver:
         cm = _canonical(cm)
         pool_stats: Dict[str, int] = {}
         t_pool = time.perf_counter()
-        subs_lb, subs_ub = self._pool_for(cm, cfg, subs, opts, pool_stats)
+        subs_lb, subs_ub = self._pool_for(cm, cfg, subs, pool_stats)
         decompose_s = time.perf_counter() - t_pool
 
         builds0 = self.stats["runner_builds"]
@@ -775,7 +833,10 @@ class Solver:
                     n_lanes=totals["n_lanes"])
                 res = dataclasses.replace(
                     res, decompose_s=decompose_s, search_s=search_s,
-                    n_decompose_dispatches=pool_stats.get("dispatches", 0))
+                    n_decompose_dispatches=pool_stats.get("dispatches", 0),
+                    n_decompose_splits=pool_stats.get("splits", 0),
+                    n_decompose_sweep_rounds=pool_stats.get(
+                        "sweep_rounds", 0))
             yield Progress(superstep=superstep, best_objective=res.objective,
                            has_solution=has, incumbent=res.solution,
                            n_nodes=res.n_nodes, n_sols=res.n_sols,
@@ -815,7 +876,6 @@ class Solver:
         if cfg.mesh is not None or cfg.mesh_shards is not None:
             raise ValueError("solve_many is single-device; it cannot be "
                              "combined with a mesh config")
-        opts = cfg.search_options()
         t0 = time.time()
         self.stats["solves"] += 1
         cms = [_canonical(cm) for cm in cms]
@@ -827,8 +887,7 @@ class Solver:
                     f"has signature {shape_signature(cm)} != {sig}")
         N = len(cms)
 
-        pools = [eps.decompose(cm, cfg.resolved_eps_target(), opts)
-                 for cm in cms]
+        pools = [self.decompose(cm, config=cfg) for cm in cms]
         smax = max(p[0].shape[0] for p in pools)
         size = _bucket(smax) if cfg.pad_pool else smax
 

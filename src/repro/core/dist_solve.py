@@ -58,7 +58,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core import eps
 from repro.core import search as S
 from repro.core.api import (CompiledRunner, Improvement, Progress,
                             SolveConfig, SolveResult, _bucket, _chunk_runner,
@@ -213,6 +212,7 @@ def _build_runner(session, cm: CompiledModel, cfg: SolveConfig,
                   mesh: Mesh, state0, n_pool: int) -> CompiledRunner:
     """One sharded chunk runner per (model shape, config, mesh size),
     cached in the session's runner cache like every other runner."""
+    session._decomposer_for(cm, cfg)
     n_dev = int(mesh.shape[AXIS])
     key = (shape_signature(cm), cfg.compile_key(), ("dist", n_dev))
     runner = session._runners.get(key)
@@ -300,7 +300,7 @@ def solve_iter_dist(session, cm: CompiledModel, cfg: SolveConfig, *,
 
     # -- pool ---------------------------------------------------------------
     if subs is None:
-        subs_lb, subs_ub = eps.decompose(cm, cfg.resolved_eps_target(), opts)
+        subs_lb, subs_ub = session.decompose(cm, config=cfg)
     else:
         subs_lb, subs_ub = np.asarray(subs[0]), np.asarray(subs[1])
     pool = _Pool(np.asarray(subs_lb), np.asarray(subs_ub), D,

@@ -39,7 +39,6 @@ import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
-from repro.core import eps
 from repro.core.api import (Improvement, LaneBatch, Progress, SolveConfig,
                             SolveResult, Solver, UNKNOWN, _bucket,
                             shape_signature)
@@ -215,9 +214,8 @@ class SolverScheduler:
             if not b.waiting:
                 break
             req, handle = b.waiting.pop(0)
-            opts = b.cfg.search_options()
-            subs_lb, subs_ub = eps.decompose(
-                req.cm, b.cfg.resolved_eps_target(), opts)
+            subs_lb, subs_ub = b.batch.session.decompose(req.cm,
+                                                         config=b.cfg)
             b.batch.splice(i, req.cm, subs_lb, subs_ub,
                            request_id=req.request_id)
             b.active[i] = _Active(request=req, handle=handle, t_admit=now,

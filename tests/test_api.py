@@ -101,20 +101,23 @@ def test_config_compile_key_ignores_budgets():
 def test_session_warm_solve_compiles_nothing():
     """The cache-hit acceptance bar: the second same-shape solve builds
     no runner and compiles no executable (asserted on the session
-    counters), and is measurably faster than the cold first."""
+    counters), and is measurably faster than the cold first.  The cold
+    solve compiles two: the chunk runner and, with it, the EPS split
+    loop."""
     cms, _, _ = _compile_zoo("knapsack", range(2))
     sess = solver.Solver(solver.SolveConfig.preset("prove", **SMALL))
     r0 = sess.solve(cms[0])
     assert sess.stats["last_solve_cold"]
     cold = sess.session_stats()
-    assert cold["runner_builds"] == 1 and cold["n_compiles"] == 1
+    assert cold["runner_builds"] == 1 and cold["n_compiles"] == 2
+    assert cold["n_decomposers"] == 1
     cold_wall = r0.wall_s
 
     r1 = sess.solve(cms[1])       # different instance, same shapes
     assert not sess.stats["last_solve_cold"]
     warm = sess.session_stats()
     assert warm["runner_builds"] == 1, "second solve rebuilt the runner"
-    assert warm["n_compiles"] == 1, "second solve recompiled"
+    assert warm["n_compiles"] == 2, "second solve recompiled"
     assert warm["runner_hits"] == 1
     assert r0.status == r1.status == solver.OPTIMAL
     # compile dominates the cold solve on these smoke instances; the
@@ -123,7 +126,25 @@ def test_session_warm_solve_compiles_nothing():
 
     # per-call config overrides that only touch host budgets still hit
     sess.solve(cms[0], timeout_s=60.0)
-    assert sess.session_stats()["n_compiles"] == 1
+    assert sess.session_stats()["n_compiles"] == 2
+
+
+def test_session_solve_given_its_pool_compiles_the_decomposition():
+    """A warm-up solve handed its pool builds the split loop with the
+    chunk runner, so the next same-shape solve that decomposes compiles
+    nothing."""
+    from repro.core import eps
+    cms, _, _ = _compile_zoo("knapsack", range(2))
+    sess = solver.Solver(solver.SolveConfig.preset("prove", **SMALL))
+    pool = eps.failed_pool(np.asarray(cms[0].lb0), np.asarray(cms[0].ub0),
+                           8)
+    sess.solve(cms[0], subs=pool)
+    warm = sess.session_stats()
+    assert warm["n_compiles"] == 2 and warm["n_decomposers"] == 1
+    res = sess.solve(cms[1])
+    assert res.n_decompose_dispatches == 2 and res.n_decompose_splits > 0
+    assert sess.session_stats()["n_compiles"] == 2
+    assert not sess.stats["last_solve_cold"]
 
 
 def test_first_solution_preset_never_claims_optimal():

@@ -119,7 +119,8 @@ def test_solver_span_tree(cm):
         (dec,) = [s for s in under if s.name == "repro.eps.decompose"]
         chunks = [s for s in under if s.name == "repro.solve.chunk"]
         dispatches = [s for s in under if s.name == "repro.eps.dispatch"]
-        assert chunks and len(dispatches) == r.n_decompose_dispatches > 1
+        # the root's fixpoint and the split loop, each with its read-back
+        assert chunks and len(dispatches) == r.n_decompose_dispatches == 2
         (pool,) = [s for s in under if s.name == "repro.solve.pool"]
         assert r.decompose_s * 1e9 >= pool.duration_ns >= dec.duration_ns
         assert r.search_s * 1e9 >= sum(c.duration_ns for c in chunks)
@@ -140,13 +141,36 @@ def test_decomposition_dispatch_counter_counts_fixpoint_calls(
     with obs.Recorder() as rec:
         eps.decompose(cm, 8, stats=stats)
     (dec,) = rec.named("repro.eps.decompose")
-    assert len(calls) > 1 and stats["dispatches"] == len(calls)
+    # one fixpoint call for the root, then the split loop's one dispatch
+    # however many splits it makes
+    assert len(calls) == 1 and stats["dispatches"] == 2
+    assert stats["splits"] == 7
     dispatches = rec.named("repro.eps.dispatch")
-    assert len(dispatches) == len(calls)
+    assert len(dispatches) == stats["dispatches"]
     assert all(d.parent_id == dec.span_id for d in dispatches)
     # a second decomposition counts afresh into the same dict
     eps.decompose(cm, 8, stats=stats)
-    assert stats["dispatches"] == len(calls) // 2
+    assert stats["dispatches"] == 2 and len(calls) == 2
+
+
+def test_chunk_runner_calls_are_search_only(cm, monkeypatch):
+    """The decomposition's split loop is compiled apart from the chunk
+    runners: a wrapper of `CompiledRunner.__call__` sees one call per
+    `repro.solve.chunk` span and none inside the decomposition."""
+    sv = _solver()
+    sv.solve(cm)                                   # compile outside
+    calls = []
+    real = api.CompiledRunner.__call__
+
+    def counted(runner, *a):
+        calls.append(obs._open._stack()[-1].name)
+        return real(runner, *a)
+
+    monkeypatch.setattr(api.CompiledRunner, "__call__", counted)
+    with obs.Recorder() as rec:
+        res = sv.solve(cm)
+    assert res.n_decompose_splits > 0
+    assert calls == ["repro.solve.chunk"] * len(rec.named("repro.solve.chunk"))
 
 
 def test_results_do_not_depend_on_the_recorder(cm):
@@ -157,7 +181,8 @@ def test_results_do_not_depend_on_the_recorder(cm):
     assert rec.spans
     for f in ("status", "objective", "n_nodes", "n_fails", "n_sols",
               "n_sweeps", "n_sweep_rounds", "n_lanes", "n_supersteps",
-              "complete", "n_decompose_dispatches"):
+              "complete", "n_decompose_dispatches", "n_decompose_splits",
+              "n_decompose_sweep_rounds"):
         assert getattr(on, f) == getattr(off, f), f
     assert (on.solution == off.solution).all()
     assert off.status == solver.OPTIMAL

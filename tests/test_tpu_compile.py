@@ -20,7 +20,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 
 from repro import solver
-from repro.core import api, dist_solve
+from repro.core import api, dist_solve, eps
 from repro.core.models import ZOO, large_instance
 from repro.distributed.sharding import dist_solve_specs
 from repro.kernels.fixpoint_kernel import MOSAIC_REFUSAL, fixpoint_pallas
@@ -107,6 +107,17 @@ def test_dist_runner_compiles_for_four_chips(mesh4, rcpsp96):
     compiled = runner.fn.lower(_shapes(rcpsp96, replicated), pool, pool,
                                carry_s).compile()
     assert "all-reduce" in compiled.as_text()
+
+
+def test_eps_split_loop_compiles_for_one_chip(one_chip, rcpsp96):
+    """(d) The EPS decomposition's split loop at the chip's pool size."""
+    cfg = _config()
+    fn = jax.jit(eps.split_program(POOL, cfg.var_strategy,
+                                   cfg.val_strategy))
+    root = jax.ShapeDtypeStruct((rcpsp96.n_vars,), rcpsp96.jdtype,
+                                sharding=one_chip)
+    compiled = fn.lower(_shapes(rcpsp96, one_chip), root, root).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**30
 
 
 @pytest.mark.xfail(strict=True, raises=NotImplementedError,
