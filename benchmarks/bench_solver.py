@@ -560,7 +560,8 @@ def run_scale_smoke(rows: List[str], timeout_s: float = 120.0,
                      if cm.ad_layout == "sparse"
                      else alldiff_dense_tile_bytes(cm.n_alldiff,
                                                    cm.ad_width, it)),
-            cumulative=(cumulative_sparse_tile_bytes(cm.cu_packed, it)
+            cumulative=(cumulative_sparse_tile_bytes(
+                            cm.n_cumulative, cm.cu_width, it)
                         if cm.cu_layout == "sparse"
                         else cumulative_dense_tile_bytes(
                             cm.n_cumulative, cm.cu_width, cm.horizon, it)))
@@ -585,7 +586,7 @@ def run_scale_smoke(rows: List[str], timeout_s: float = 120.0,
             kind="large", model=name, instance=inst.name,
             n_vars=cm.n_vars, n_props=cm.total_props,
             ad_layout=cm.ad_layout, cu_layout=cm.cu_layout,
-            ad_packed=cm.ad_packed, cu_packed=cm.cu_packed,
+            ad_packed=cm.ad_packed,
             peak_bank_tile_bytes=bank_bytes,
             root_fixpoint_sweeps=sweeps,
             props_per_sec=props_per_sec,

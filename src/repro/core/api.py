@@ -335,8 +335,7 @@ def shape_signature(cm: CompiledModel) -> tuple:
     return (cm.n_vars, cm.n_props, cm.k_terms, cm.d_occ,
             cm.n_alldiff, cm.ad_width, cm.ad_docc,
             cm.n_cumulative, cm.cu_width, cm.cu_docc, cm.horizon,
-            cm.ad_layout, cm.ad_packed, cm.cu_layout, cm.cu_packed,
-            cm.cu_optional,
+            cm.ad_layout, cm.ad_packed, cm.cu_layout, cm.cu_optional,
             # §17 extensional bank layout + bitset word count: mixed
             # table/bounds models (and different table geometries) must
             # never collide in the compiled-runner cache

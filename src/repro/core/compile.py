@@ -9,26 +9,27 @@ Every constraint becomes one row of a *typed propagator table*
   filtered with Hall-interval bounds(Z) consistency;
 * ``Cumulative``  (cu_svar/cu_dur/cu_dem/cu_cap): one row per cumulative,
   filtered with time-table (compulsory-part) reasoning; rows with
-  optional members add a presence column (cu_pres/cu_pk_pres, DESIGN.md
-  §18) under the static flag ``cu_optional``.
+  optional members add a presence column (cu_pres, DESIGN.md §18) under
+  the static flag ``cu_optional``.
 
 Each bank gets its own variable-centric occurrence tables so every kind
 joins into the store by pure gathers (TPU-native, no atomics); each bank
 carries one trailing neutral dummy row that occurrence padding points at.
 
-Since ISSUE-9 the native banks also come in a **CSR-style packed view**
-(DESIGN.md §16): all members of all rows of a kind concatenated along one
-packed axis (``ad_pk_*``/``cu_pk_*`` with a segment id per member and
-``ad_ptr``/``cu_ptr`` row pointers), so the O(N³) dense Hall tensor and
-the dense ``[.., horizon]`` time grid can be replaced by O(M²) segmented
-tiles at scale.  The layout each bank's *tile* uses is chosen here at
-compile time (``bank_layout="auto"``): dense below `DENSE_TILE_MAX_BYTES`
-of estimated per-lane sweep scratch, sparse above — and the choice is a
-static field (`ad_layout`/`cu_layout`) that flows into
-`api.shape_signature`, so cached runners never mix layouts.  Both views
-are always emitted (the packed tables are O(model size)); forcing
-``bank_layout="dense"`` past `DENSE_TILE_HARD_BYTES` raises instead of
-letting XLA/Mosaic OOM opaquely.
+Each native bank also has a *sparse* tile (DESIGN.md §16) that replaces
+the O(N³) dense Hall tensor and the dense ``[.., horizon]`` time grid at
+scale.  The AllDifferent one runs over a **CSR-style packed view**: all
+members of all rows concatenated along one packed axis (``ad_pk_*`` with
+a segment id per member and ``ad_ptr`` row pointers).  The Cumulative
+one reads the dense bank itself, each row a block of ``cu_width`` member
+slots, and scans each row's events alone.  The layout each bank's *tile*
+uses is chosen here at compile time (``bank_layout="auto"``): dense below
+`DENSE_TILE_MAX_BYTES` of estimated per-lane sweep scratch, sparse above
+— and the choice is a static field (`ad_layout`/`cu_layout`) that flows
+into `api.shape_signature`, so cached runners never mix layouts.  Both
+AllDifferent views are always emitted (the packed tables are O(model
+size)); forcing ``bank_layout="dense"`` past `DENSE_TILE_HARD_BYTES`
+raises instead of letting XLA/Mosaic OOM opaquely.
 
 For the linear bank, two dual views of the same program are produced:
 
@@ -108,13 +109,19 @@ def alldiff_sparse_tile_bytes(ad_packed: int, itemsize: int) -> int:
     return 6 * ad_packed ** 2 * itemsize
 
 
-def cumulative_sparse_tile_bytes(cu_packed: int, itemsize: int,
-                                 optional: bool = False) -> int:
-    """Per-lane scratch of `cumulative_candidates_sparse_tile`: event
-    arrays linear in M plus one [M, 2M] boolean overload reduction, plus
-    the presence reads and candidates of optional members (~4 [M])."""
-    opt = 4 * cu_packed * itemsize if optional else 0
-    return (2 * cu_packed ** 2) + 16 * cu_packed * itemsize + opt
+def cumulative_sparse_tile_bytes(n_cumulative: int, cu_width: int,
+                                 itemsize: int, optional: bool = False
+                                 ) -> int:
+    """Per-lane scratch of `cumulative_candidates_sparse_tile` over its
+    M = (C+1)·T member slots, padding included: the row-blocked event
+    arrays ([C+1, 2T], ~8 live), each row's events broadcast over its
+    slots for the scans ([2T, M], ~3 live), plus the presence reads and
+    candidates of optional members (~4 [M])."""
+    if not n_cumulative:
+        return 0
+    slots = (n_cumulative + 1) * cu_width
+    opt = 4 * slots * itemsize if optional else 0
+    return (3 * 2 * cu_width + 16) * slots * itemsize + opt
 
 
 def ct_tile_bytes(n_table: int, ct_arity: int, n_words: int,
@@ -187,20 +194,14 @@ class CompiledModel:
     # cu_optional, of presence literals past them (DESIGN.md §18)
     cu_occ_inst: jax.Array  # i[V, Dcu]
     cu_occ_pos: jax.Array   # i[V, Dcu]
-    # CSR-style packed views of the native banks (DESIGN.md §16): members
-    # of all rows concatenated (row-contiguous, so member (a, n) sits at
-    # flat index ptr[a] + n and the dense occ tables double as flat
-    # indices); padding slots carry seg == n_rows (the dummy).
+    # CSR-style packed view of the AllDifferent bank (DESIGN.md §16):
+    # members of all rows concatenated (row-contiguous, so member (a, n)
+    # sits at flat index ptr[a] + n and the dense occ tables double as
+    # flat indices); padding slots carry seg == n_rows (the dummy).
     ad_ptr: jax.Array       # i[A+2]   row pointers into the packed axis
     ad_pk_var: jax.Array    # i[Mad]   packed member var index
     ad_pk_off: jax.Array    # i[Mad]   packed member offset
     ad_pk_seg: jax.Array    # i[Mad]   owning row; == A for padding
-    cu_ptr: jax.Array       # i[C+2]
-    cu_pk_svar: jax.Array   # i[Mcu]
-    cu_pk_dur: jax.Array    # i[Mcu]
-    cu_pk_dem: jax.Array    # i[Mcu]
-    cu_pk_pres: jax.Array   # i[Mcu]   presence literal ([1] without cu_optional)
-    cu_pk_seg: jax.Array    # i[Mcu]   owning row; == C for padding
     # compact-table bank (row T is the neutral dummy; DESIGN.md §17):
     # supports are packed tuple bitsets per (member slot, value index k),
     # where k indexes value dom_off[var] + k in the bitset domain layout
@@ -228,12 +229,11 @@ class CompiledModel:
     cu_width: int = dataclasses.field(metadata=dict(static=True))
     cu_docc: int = dataclasses.field(metadata=dict(static=True))
     horizon: int = dataclasses.field(metadata=dict(static=True))
-    # tile layout per native bank ("dense" | "sparse") + packed lengths;
+    # tile layout per native bank ("dense" | "sparse") + packed length;
     # static so the choice shapes the trace and the runner cache key
     ad_layout: str = dataclasses.field(metadata=dict(static=True))
     cu_layout: str = dataclasses.field(metadata=dict(static=True))
     ad_packed: int = dataclasses.field(metadata=dict(static=True))
-    cu_packed: int = dataclasses.field(metadata=dict(static=True))
     # some Cumulative member is optional (has a presence literal): the
     # tiles then run the presence path and the bank carries cu_pres
     cu_optional: bool = dataclasses.field(metadata=dict(static=True))
@@ -415,31 +415,6 @@ def compile_model(
     if C:
         horizon = _round_up(horizon, pad_horizon_to)
 
-    # packed (CSR) view of the cumulative bank (same invariants as ad_*)
-    mcu_real = sum(len(cu.starts) for cu in m.cumulatives)
-    Mcu = max(_round_up(mcu_real + 1, 8), 8)
-    cu_ptr = np.zeros((C + 2,), dtype=np.int64)
-    cu_pk_svar = np.zeros((Mcu,), dtype=np.int64)
-    cu_pk_dur = np.zeros((Mcu,), dtype=np.int64)
-    cu_pk_dem = np.zeros((Mcu,), dtype=np.int64)
-    cu_pk_pres = np.full((Mcu,) if cu_optional else (1,), TRUE_VAR,
-                         dtype=np.int64)
-    cu_pk_seg = np.full((Mcu,), C, dtype=np.int64)
-    k_ = 0
-    for c, cu in enumerate(m.cumulatives):
-        cu_ptr[c] = k_
-        for t, (v, d_, r_) in enumerate(zip(cu.starts, cu.durations,
-                                            cu.demands)):
-            cu_pk_svar[k_] = v
-            cu_pk_dur[k_] = d_
-            cu_pk_dem[k_] = r_
-            if cu_optional:
-                cu_pk_pres[k_] = cu_pres[c, t]
-            cu_pk_seg[k_] = c
-            k_ += 1
-    cu_ptr[C] = k_
-    cu_ptr[C + 1] = Mcu
-
     # ---- compact-table bank + bitset domain layout (DESIGN.md §17) ------
     branch = list(m.branch_order) if m.branch_order else list(range(1, V))
     # ensure every non-fixed var is ultimately branchable: append leftovers
@@ -506,8 +481,9 @@ def compile_model(
     if C:
         worst = max(worst, horizon + 2,
                     int(cu_dem[:C].sum(axis=1).max()), int(cu_cap[:C].max()))
-    # sparse tiles compare member *counts* against interval widths
-    worst = max(worst, Mad, Mcu)
+    # the sparse AllDifferent tile compares member *counts* against
+    # interval widths
+    worst = max(worst, Mad)
     # bitset hull bridge: an empty tracked domain reads back as
     # (off + 32·n_words, off - 1)
     worst = max(worst, int(np.abs(lb0).max()) + K32 + 2)
@@ -534,11 +510,10 @@ def compile_model(
         bank_layout, cumulative_dense_tile_bytes(C, T, horizon, itemsize,
                                                  cu_optional),
         "Cumulative", m.name)
-    # presence candidates follow the start candidates of the tile: at
-    # member position T + t of a dense row, at M + k of the packed axis
-    pres_base = T if cu_layout == "dense" else Mcu
+    # presence candidates follow the start candidates of either tile, at
+    # member position T + t of the row
     for p_, c, t in pres_occs:
-        cu_occs[p_].append((c, pres_base + t))
+        cu_occs[p_].append((c, T + t))
     Dcu = max(max((len(o) for o in cu_occs), default=1), 1)
     Dcu = _round_up(Dcu, 4) if C else 1
     cu_occ_inst = np.full((V, Dcu), C, dtype=np.int64)   # pad -> dummy row
@@ -561,9 +536,6 @@ def compile_model(
         cu_occ_inst=cast(cu_occ_inst), cu_occ_pos=cast(cu_occ_pos),
         ad_ptr=cast(ad_ptr), ad_pk_var=cast(ad_pk_var),
         ad_pk_off=cast(ad_pk_off), ad_pk_seg=cast(ad_pk_seg),
-        cu_ptr=cast(cu_ptr), cu_pk_svar=cast(cu_pk_svar),
-        cu_pk_dur=cast(cu_pk_dur), cu_pk_dem=cast(cu_pk_dem),
-        cu_pk_pres=cast(cu_pk_pres), cu_pk_seg=cast(cu_pk_seg),
         ct_vars=cast(ct_vars), ct_mask=cast(ct_mask),
         ct_supp=jnp.asarray(ct_supp),
         ct_occ_inst=cast(ct_occ_inst), ct_occ_pos=cast(ct_occ_pos),
@@ -573,7 +545,7 @@ def compile_model(
         n_alldiff=A, ad_width=N, ad_docc=Dad,
         n_cumulative=C, cu_width=T, cu_docc=Dcu, horizon=horizon,
         ad_layout=ad_layout, cu_layout=cu_layout,
-        ad_packed=Mad, cu_packed=Mcu, cu_optional=cu_optional,
+        ad_packed=Mad, cu_optional=cu_optional,
         n_table=Tn, ct_arity=R, ct_words=TW, ct_docc=Dct, n_words=n_words,
         obj_var=(m.objective if m.objective is not None else -1),
         dtype=dtype, name=m.name,

@@ -361,102 +361,106 @@ def alldiff_candidates_sparse_tile(lb, ub, ad_pk_var, ad_pk_off, ad_pk_seg,
     return cand_lb, cand_ub
 
 
-def cumulative_candidates_sparse_tile(lb, ub, cu_pk_svar, cu_pk_dur,
-                                      cu_pk_dem, cu_pk_seg, cu_cap,
-                                      n_cumulative: int, cu_pk_pres=None
+def cumulative_candidates_sparse_tile(lb, ub, cu_svar, cu_dur, cu_dem,
+                                      cu_cap, cu_pres=None
                                       ) -> Tuple[jax.Array, jax.Array]:
     """Event-based time-table pass — the scale variant of
     `cumulative_candidates_tile` (DESIGN.md §16).
 
-    Same compulsory-part semantics, never materialises the ``[.., T,
-    horizon]`` grid: each effective task with a compulsory part emits two
-    events (+q at lst, −q at ect); events lexsorted by (segment, time,
-    end-before-start) give the piecewise-constant profile as one global
-    cumsum (per-seg exact because each segment's deltas sum to 0 under
-    the seg-major sort).  Consecutive same-segment events bound disjoint
-    constant-profile intervals [u, v); empty ones (u == v) are guarded
-    off.  Overload and per-task forbidden windows are tested per
-    interval, and the first/last feasible start is found by one forward
-    and one backward `lax.scan` over the 2M events with a monotone jump
-    carry — single-pass exact because the intervals are disjoint and
-    sorted.  Bit-equal to the dense tile per task on non-failed stores.
+    Same compulsory-part semantics over the same ``[C1, T]`` bank, but
+    never materialises the ``[.., T, horizon]`` grid.  Each row is a
+    block of T member slots.  Every slot emits two events, +q at lst
+    and −q at ect (0 without a compulsory part), and each row's 2T
+    events are sorted by time within the row, the row's ineffective
+    slots (padding, zero duration or demand) last.  The row's cumsum
+    is then its piecewise-constant profile, and consecutive effective
+    events bound disjoint constant-profile intervals [u, v); the row's
+    last effective event and every ineffective one own an empty
+    interval, which the u < v guard disables.  Events at one time need
+    no order, ends and starts alike: all but the last of them own empty
+    [t, t) intervals, and the last carries the same prefix sum whatever
+    the order.
 
-    Returns (cand_lb, cand_ub), each ``[L, M]`` over the packed axis;
-    with ``cu_pk_pres`` (presence literals, DESIGN.md §18) each
-    ``[L, 2M]``, the presence tells at ``M + k`` — the same optional
-    semantics as the dense tile.
+    Overload is tested per row.  A member's forbidden windows come only
+    from its own row's events, so the first (last) feasible start of
+    every member is one forward (backward) scan of 2T steps with a
+    monotone jump carry — single-pass exact because the intervals are
+    disjoint and sorted.  All rows scan at once on the flat ``[L, C1·T]``
+    slot axis, each row's event broadcast over its T slots, and both
+    scans share one loop.  Bit-equal to the dense tile per task on
+    non-failed stores.
+
+    Returns (cand_lb, cand_ub) of the dense tile's shapes, each
+    ``[L, C1, T]``; with ``cu_pres`` (presence literals, DESIGN.md §18)
+    each ``[L, C1, 2T]``, the presence tells at ``T + t`` — the same
+    optional semantics as the dense tile.
     """
     dt = lb.dtype
     neu_ub, neu_lb = _neutrals(dt)
     zero = jnp.asarray(0, dt)
-    M = cu_pk_svar.shape[0]
-    seg = cu_pk_seg
-    d = cu_pk_dur[None]                                     # [1, M]
-    q = cu_pk_dem[None]
-    act = (seg < n_cumulative)[None] & (d > 0) & (q > 0)
+    L = lb.shape[0]
+    C1, T = cu_svar.shape
+    M = C1 * T                                              # slots
+
+    def rows(a):                                    # [.., M] → [.., C1, T]
+        return a.reshape(a.shape[:-1] + (C1, T))
+
+    d = cu_dur.reshape(1, M)
+    q = cu_dem.reshape(1, M)
+    act = (d > 0) & (q > 0)
     present = act
-    if cu_pk_pres is not None:
-        present, undecided = _presence(lb, ub, cu_pk_pres, act)
-    cap = jnp.take(cu_cap, seg)[None]                       # [1, M] per task
-    est = jnp.take(lb, cu_pk_svar, axis=1)                  # [L, M]
-    lst = jnp.take(ub, cu_pk_svar, axis=1)
+    if cu_pres is not None:
+        present, undecided = _presence(lb, ub, cu_pres.reshape(-1), act)
+    cap = jnp.repeat(cu_cap, T)[None]                       # [1, M] per slot
+    est = jnp.take(lb, cu_svar.reshape(-1), axis=1)         # [L, M]
+    lst = jnp.take(ub, cu_svar.reshape(-1), axis=1)
     ect = est + d
     has_cp = present & (lst < ect)                          # compulsory part
 
-    times = jnp.concatenate([lst, ect], axis=1)             # [L, 2M]
-    delta = jnp.concatenate([jnp.where(has_cp, q, zero),
-                             jnp.where(has_cp, -q, zero)], axis=1)
-    esegb = jnp.broadcast_to(
-        jnp.concatenate([seg, seg])[None], times.shape)
-    # ends sort before starts at equal times: transient profiles are then
-    # confined to empty [t, t) intervals, which the u < v guard disables
-    kindb = jnp.broadcast_to(jnp.concatenate(
-        [jnp.ones((M,), jnp.int32), jnp.zeros((M,), jnp.int32)])[None],
-        times.shape)
-    perm = jnp.lexsort((kindb, times, esegb), axis=-1)      # seg, time, kind
-    stime = jnp.take_along_axis(times, perm, axis=1)
-    sdelta = jnp.take_along_axis(delta, perm, axis=1)
-    sseg = jnp.take_along_axis(esegb, perm, axis=1)
-    prof = jnp.cumsum(sdelta, axis=1)                       # [L, 2M]
+    times = jnp.concatenate([rows(lst), rows(ect)], axis=2)  # [L, C1, 2T]
+    delta = jnp.concatenate([rows(jnp.where(has_cp, q, zero)),
+                             rows(jnp.where(has_cp, -q, zero))], axis=2)
+    eff = jnp.broadcast_to(
+        jnp.concatenate([rows(act)] * 2, axis=2), times.shape)
+    perm = jnp.lexsort((times, (~eff).astype(jnp.int32)), axis=-1)
+    stime = jnp.take_along_axis(times, perm, axis=2)
+    sdelta = jnp.take_along_axis(delta, perm, axis=2)
+    seff = jnp.take_along_axis(eff, perm, axis=2)
+    prof = jnp.cumsum(sdelta, axis=2)                       # [L, C1, 2T]
 
-    # event e owns [u, v) up to the next event while it stays in-segment;
-    # the last event of a segment owns an empty (disabled) interval
-    nxt_t = jnp.concatenate([stime[:, 1:], stime[:, -1:]], axis=1)
-    nxt_s = jnp.concatenate(
-        [sseg[:, 1:], jnp.full_like(sseg[:, -1:], -1)], axis=1)
+    # an effective event owns [u, v) up to the next effective event of
+    # its row; the row's last one, and every ineffective one, u == v
+    nxt_t = jnp.concatenate([stime[..., 1:], stime[..., -1:]], axis=2)
+    nxt_e = jnp.concatenate(
+        [seff[..., 1:], jnp.zeros_like(seff[..., :1])], axis=2)
     u_t = stime
-    v_t = jnp.where(nxt_s == sseg, nxt_t, stime)
-    over_e = (u_t < v_t) & (prof > jnp.take(cu_cap, sseg))  # [L, 2M]
-    # per-task overload: any overloaded interval in my segment
-    ovl = jnp.any((sseg[:, None, :] == seg[None, :, None])
-                  & over_e[:, None, :], axis=2)             # [L, M]
+    v_t = jnp.where(seff & nxt_e, nxt_t, stime)
+    over = (u_t < v_t) & (prof > cu_cap[None, :, None])
+    ovl = jnp.repeat(over.any(axis=2), T, axis=1)          # [L, M] per row
 
-    # forbidden-window scans: task t cannot run through interval [u, v)
+    # forbidden windows: member t cannot run through [u, v) of its row
     # if profile₋t + q_t > cap there (profile₋t removes t's own
     # compulsory part, tested at u only — CP endpoints are events, so
-    # coverage is constant on [u, v))
-    def _bad(u_, v_, p_, sg):
-        segok = sg[:, None] == seg[None, :]                 # [L, M]
-        cov = has_cp & (u_ >= lst) & (u_ < ect)
-        return (segok & act & (u_ < v_)
-                & (p_ + jnp.where(cov, zero, q) > cap))
+    # coverage is constant on [u, v)).  An interval that forbids nothing
+    # gets u = +big, so no start can reach it.
+    def on_slots(a):                                        # → [2T, L, M]
+        a = jnp.moveaxis(a, 2, 0)[..., None]
+        return jnp.broadcast_to(a, a.shape[:-1] + (T,)).reshape(2 * T, L, M)
 
-    def fwd(s, ev):
-        u, v, p, sg = ev
-        u_, v_, p_ = u[:, None], v[:, None], p[:, None]
-        hit = _bad(u_, v_, p_, sg) & (s < v_) & (s + d > u_)
-        return jnp.where(hit, v_, s), None
+    u_e, v_e, p_e = on_slots(u_t), on_slots(v_t), on_slots(prof)
+    cov = has_cp & (u_e >= lst) & (u_e < ect)
+    bad = act & (u_e < v_e) & (p_e + jnp.where(cov, zero, q) > cap)
+    u_e = jnp.where(bad, u_e, neu_ub)
 
-    def bwd(s, ev):
-        u, v, p, sg = ev
-        u_, v_, p_ = u[:, None], v[:, None], p[:, None]
-        hit = _bad(u_, v_, p_, sg) & (s < v_) & (s + d > u_)
-        return jnp.where(hit, u_ - d, s), None
+    def scan(e, s):
+        s_f, s_b = s                       # first ≥ est, last ≤ lst
+        u_f, v_f = u_e[e], v_e[e]
+        u_b, v_b = u_e[2 * T - 1 - e], v_e[2 * T - 1 - e]
+        s_f = jnp.where((s_f < v_f) & (s_f + d > u_f), v_f, s_f)
+        s_b = jnp.where((s_b < v_b) & (s_b + d > u_b), u_b - d, s_b)
+        return s_f, s_b
 
-    xs = (jnp.moveaxis(u_t, 1, 0), jnp.moveaxis(v_t, 1, 0),
-          jnp.moveaxis(prof, 1, 0), jnp.moveaxis(sseg, 1, 0))
-    s_est, _ = lax.scan(fwd, est, xs)                # first feasible ≥ est
-    s_lst, _ = lax.scan(bwd, lst, xs, reverse=True)  # last feasible ≤ lst
+    s_est, s_lst = lax.fori_loop(0, 2 * T, scan, (est, lst))
 
     cand_lb = s_est
     # no feasible start ≥ 0 ⇒ dense's max over an empty set = −big
@@ -471,12 +475,13 @@ def cumulative_candidates_sparse_tile(lb, ub, cu_pk_svar, cu_pk_dur,
     cand_ub = jnp.where(present, cand_ub, neu_ub + zero)
     # overload: fail every present task of the row
     cand_lb = jnp.where(ovl & present, -neu_lb + zero, cand_lb)
-    if cu_pk_pres is None:
+    cand_lb, cand_ub = rows(cand_lb), rows(cand_ub)
+    if cu_pres is None:
         return cand_lb, cand_ub
-    p_lb, p_ub = _presence_tells(undecided, first > lst, neu_lb + zero,
-                                 neu_ub + zero)
-    return (jnp.concatenate([cand_lb, p_lb], axis=1),
-            jnp.concatenate([cand_ub, p_ub], axis=1))
+    p_lb, p_ub = _presence_tells(rows(undecided), rows(first > lst),
+                                 neu_lb + zero, neu_ub + zero)
+    return (jnp.concatenate([cand_lb, p_lb], axis=2),
+            jnp.concatenate([cand_ub, p_ub], axis=2))
 
 
 def _gather_join(cand_lb, cand_ub, occ_inst, occ_pos, L):
@@ -610,8 +615,7 @@ def sweep_tile(lb, ub, vidx, coef, rhs, bidx, occ_prop, occ_slot,
                ad_vars, ad_offs, ad_mask, ad_occ_inst, ad_occ_pos,
                ad_ptr, ad_pk_var, ad_pk_off, ad_pk_seg,
                cu_svar, cu_dur, cu_dem, cu_cap, cu_pres, cu_occ_inst,
-               cu_occ_pos, cu_ptr, cu_pk_svar, cu_pk_dur, cu_pk_dem,
-               cu_pk_pres, cu_pk_seg,
+               cu_occ_pos,
                ct_vars, ct_mask, ct_supp, ct_occ_inst, ct_occ_pos,
                dom_off, dom_track,
                box_lo, box_hi, *, horizon: int, n_alldiff: int = 0,
@@ -628,11 +632,11 @@ def sweep_tile(lb, ub, vidx, coef, rhs, bidx, occ_prop, occ_slot,
     associativity/commutativity of ⊔ makes the kind order irrelevant to
     the result.  ``n_alldiff``/``n_cumulative`` are compile-time statics
     so models without a bank skip its (dummy-only) work entirely;
-    ``ad_layout``/``cu_layout`` pick the dense or the packed/segmented
-    tile per bank (compile-time crossover, DESIGN.md §16) — same
-    semantics, different scratch scaling.  ``cu_optional`` (static)
-    switches the Cumulative tile to its presence path (DESIGN.md §18);
-    off, the presence tables are ``[1, 1]``/``[1]`` dummies and unread.
+    ``ad_layout``/``cu_layout`` pick the dense or the sparse tile per
+    bank (compile-time crossover, DESIGN.md §16) — same semantics,
+    different scratch scaling.  ``cu_optional`` (static) switches the
+    Cumulative tile to its presence path (DESIGN.md §18); off, the
+    presence table is a ``[1, 1]`` dummy and unread.
 
     With ``n_table`` tables (DESIGN.md §17) the sweep also runs the
     Compact-Table tile over the bitset domain.  `dom` (``[L, V, W]``
@@ -667,19 +671,15 @@ def sweep_tile(lb, ub, vidx, coef, rhs, bidx, occ_prop, occ_slot,
         g_ub = jnp.minimum(g_ub, j_ub)
     if n_cumulative:
         with jax.named_scope("tile.cumulative"):
+            pres = cu_pres if cu_optional else None
             if cu_layout == "sparse":
                 cu_lb, cu_ub = cumulative_candidates_sparse_tile(
-                    lb, ub, cu_pk_svar, cu_pk_dur, cu_pk_dem, cu_pk_seg,
-                    cu_cap, n_cumulative,
-                    cu_pk_pres if cu_optional else None)
-                occ = jnp.take(cu_ptr, cu_occ_inst) + cu_occ_pos  # [V, Dcu]
-                j_lb, j_ub = _gather_join_flat(cu_lb, cu_ub, occ, L)
+                    lb, ub, cu_svar, cu_dur, cu_dem, cu_cap, pres)
             else:
                 cu_lb, cu_ub = cumulative_candidates_tile(
-                    lb, ub, cu_svar, cu_dur, cu_dem, cu_cap, horizon,
-                    cu_pres if cu_optional else None)
-                j_lb, j_ub = _gather_join(cu_lb, cu_ub, cu_occ_inst,
-                                          cu_occ_pos, L)
+                    lb, ub, cu_svar, cu_dur, cu_dem, cu_cap, horizon, pres)
+            j_lb, j_ub = _gather_join(cu_lb, cu_ub, cu_occ_inst,
+                                      cu_occ_pos, L)
         g_lb = jnp.maximum(g_lb, j_lb)
         g_ub = jnp.minimum(g_ub, j_ub)
     if n_table:
@@ -713,8 +713,7 @@ TABLE_NAMES = (
     "ad_vars", "ad_offs", "ad_mask", "ad_occ_inst", "ad_occ_pos",
     "ad_ptr", "ad_pk_var", "ad_pk_off", "ad_pk_seg",
     "cu_svar", "cu_dur", "cu_dem", "cu_cap", "cu_pres", "cu_occ_inst",
-    "cu_occ_pos", "cu_ptr", "cu_pk_svar", "cu_pk_dur", "cu_pk_dem",
-    "cu_pk_pres", "cu_pk_seg",
+    "cu_occ_pos",
     "ct_vars", "ct_mask", "ct_supp", "ct_occ_inst", "ct_occ_pos",
     "dom_off", "dom_track", "box_lo", "box_hi")
 
@@ -813,19 +812,17 @@ def sweep_scatter(cm: CompiledModel, lb: jax.Array, ub: jax.Array, dom=None):
             jnp.minimum(ad_lb[0].reshape(-1), cm.box_hi[v]))
     if cm.n_cumulative:
         opt = cm.cu_optional
+        pres = cm.cu_pres if opt else None
         if cm.cu_layout == "sparse":
             cu_lb, cu_ub = cumulative_candidates_sparse_tile(
-                lb[None], ub[None], cm.cu_pk_svar, cm.cu_pk_dur,
-                cm.cu_pk_dem, cm.cu_pk_seg, cm.cu_cap, cm.n_cumulative,
-                cm.cu_pk_pres if opt else None)
-            v = (jnp.concatenate([cm.cu_pk_svar, cm.cu_pk_pres]) if opt
-                 else cm.cu_pk_svar)
+                lb[None], ub[None], cm.cu_svar, cm.cu_dur, cm.cu_dem,
+                cm.cu_cap, pres)
         else:
             cu_lb, cu_ub = cumulative_candidates_tile(
                 lb[None], ub[None], cm.cu_svar, cm.cu_dur, cm.cu_dem,
-                cm.cu_cap, cm.horizon, cm.cu_pres if opt else None)
-            v = (jnp.concatenate([cm.cu_svar, cm.cu_pres], axis=1) if opt
-                 else cm.cu_svar).reshape(-1)
+                cm.cu_cap, cm.horizon, pres)
+        v = (jnp.concatenate([cm.cu_svar, cm.cu_pres], axis=1) if opt
+             else cm.cu_svar).reshape(-1)
         new_ub = new_ub.at[v].min(
             jnp.maximum(cu_ub[0].reshape(-1), cm.box_lo[v]))
         new_lb = new_lb.at[v].max(

@@ -137,7 +137,8 @@ def vmem_budget(cm, lane_tile: int, *, resident: bool = False,
             else alldiff_dense_tile_bytes(cm.n_alldiff, N, it))
     if cm.n_cumulative:
         scratch += lane_tile * (
-            cumulative_sparse_tile_bytes(cm.cu_packed, it, cm.cu_optional)
+            cumulative_sparse_tile_bytes(cm.n_cumulative, T, it,
+                                         cm.cu_optional)
             if cm.cu_layout == "sparse"
             else cumulative_dense_tile_bytes(cm.n_cumulative, T,
                                              cm.horizon, it,

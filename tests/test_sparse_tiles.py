@@ -17,6 +17,7 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
+from repro import solver
 from repro.core import api, eps, search as S
 from repro.core.backend import get_backend
 from repro.core.compile import (DENSE_TILE_MAX_BYTES,
@@ -25,8 +26,8 @@ from repro.core.compile import (DENSE_TILE_MAX_BYTES,
 from repro.core.fixpoint import fixpoint_batch, sweep_batch, \
     sweep_scatter_batch
 from repro.core.model import Model
-from repro.core.models import ZOO, ground_check, large_instance, nqueens, \
-    rcpsp
+from repro.core.models import ZOO, ground_check, jobshop, large_instance, \
+    nqueens, rcpsp
 from util import random_substores, solve_session
 
 ALL = ("gather", "scatter", "pallas")
@@ -86,6 +87,123 @@ def test_sparse_dense_per_sweep_parity():
                                       err_msg=f"{name} scatter lb")
         np.testing.assert_array_equal(np.asarray(du), np.asarray(su),
                                       err_msg=f"{name} scatter ub")
+
+
+def _cumulative_case(case):
+    """A model whose Cumulative bank exercises one corner of the sparse
+    tile's row-blocked event scan (DESIGN.md §16)."""
+    m = Model(case)
+    if case == "jobshop-6x6":
+        m, _ = jobshop.build_model(jobshop.generate(6, n_machines=6, seed=4))
+        return m
+    if case == "uneven-rows":
+        # rows of 1, 4 and 15 members: the short rows' blocks are mostly
+        # padding slots, which must stay inert
+        xs = [m.int_var(0, 30, f"s{i}") for i in range(20)]
+        m.cumulative(xs[:1], [4], [1], 1)
+        m.cumulative(xs[1:5], [3, 2, 4, 1], [1, 2, 1, 1], 2)
+        m.cumulative(xs[5:], [1 + i % 4 for i in range(15)],
+                     [1 + i % 3 for i in range(15)], 3)
+    elif case == "tied-times":
+        # equal durations and windows: many starts and ends per time point
+        xs = [m.int_var(0, 8, f"s{i}") for i in range(10)]
+        m.cumulative(xs, [2] * 10, [1] * 10, 2)
+    elif case == "zero-duration-demand":
+        xs = [m.int_var(0, 12, f"s{i}") for i in range(8)]
+        m.cumulative(xs, [3, 0, 2, 4, 0, 2, 3, 1], [1, 1, 0, 1, 0, 1, 1, 0],
+                     1)
+    elif case == "lone-task-over-capacity":
+        # member 1 asks 3 of capacity 2: optional, so that a store fails
+        # only once it is present
+        xs = [m.int_var(0, 20, f"s{i}") for i in range(6)]
+        one, p = m.int_var(1, 1, "one"), m.bool_var("p")
+        m.cumulative(xs, [3, 2, 4, 2, 3, 1], [1, 3, 1, 2, 1, 1], 2,
+                     presence=[one, p, one, one, one, one])
+        xs = xs + [p]
+    elif case == "overloaded-row":
+        # five unit tasks of length 4 in [0, 6] on capacity 2: once three
+        # windows are narrow their compulsory parts overlap
+        xs = [m.int_var(0, 6, f"s{i}") for i in range(5)]
+        m.cumulative(xs, [4] * 5, [1] * 5, 2)
+    else:                                   # optional-members
+        xs = [m.int_var(0, 16, f"s{i}") for i in range(8)]
+        ps = [m.bool_var(f"p{i}") for i in range(8)]
+        m.cumulative(xs, [3, 2, 4, 2, 3, 2, 1, 3], [1, 2, 1, 1, 2, 1, 1, 2],
+                     2, presence=ps)
+        m.cumulative(xs[:4], [3, 2, 4, 2], [1, 1, 1, 1], 1, presence=ps[:4])
+        xs = xs + ps
+    m.branch_on(xs)
+    return m
+
+
+def _window_stores(rng, cm, n):
+    """n stores with many narrow start windows, so that members carry
+    compulsory parts, rows overload and some stores fail."""
+    lb0, ub0 = np.asarray(cm.lb0), np.asarray(cm.ub0)
+    lbs, ubs = np.tile(lb0, (n, 1)), np.tile(ub0, (n, 1))
+    for i in range(n):
+        p = rng.uniform(0.05, 0.8)
+        for v in range(1, cm.n_vars):
+            if lb0[v] < ub0[v] and rng.random() < p:
+                c = int(rng.integers(lb0[v], ub0[v] + 1))
+                w = int(rng.integers(0, 1 + (ub0[v] - lb0[v]) // 4))
+                lbs[i, v] = max(lb0[v], c - w)
+                ubs[i, v] = min(ub0[v], c + w)
+    return lbs, ubs
+
+
+@pytest.mark.parametrize("case", [
+    "uneven-rows", "tied-times", "zero-duration-demand",
+    "lone-task-over-capacity", "overloaded-row", "jobshop-6x6",
+    "optional-members"])
+def test_sparse_cumulative_matches_dense_per_sweep(case):
+    """The row-blocked sparse Cumulative tile against the dense tile,
+    sweep by sweep, gather and scatter: equal failed masks, and equal
+    stores wherever the store has not failed."""
+    dn, sp = _compile_pair(_cumulative_case(case))
+    assert dn.cu_layout == "dense" and sp.cu_layout == "sparse"
+    rng = np.random.default_rng(sum(map(ord, case)))
+    lbs, ubs = _window_stores(rng, dn, 24)
+    n_live = n_failed = 0
+    for sweep in (sweep_batch, sweep_scatter_batch):
+        dl = sl = jnp.asarray(lbs)
+        du = su = jnp.asarray(ubs)
+        for k in range(6):
+            dl, du = sweep(dn, dl, du)
+            sl, su = sweep(sp, sl, su)
+            a = [np.asarray(x) for x in (dl, du, sl, su)]
+            failed = (a[0] > a[1]).any(axis=1)
+            np.testing.assert_array_equal(
+                failed, (a[2] > a[3]).any(axis=1),
+                err_msg=f"{case} {sweep.__name__} sweep {k} failed mask")
+            ok = ~failed
+            np.testing.assert_array_equal(
+                a[0][ok], a[2][ok], err_msg=f"{case} sweep {k} lb")
+            np.testing.assert_array_equal(
+                a[1][ok], a[3][ok], err_msg=f"{case} sweep {k} ub")
+            n_live += int(ok.sum())
+            n_failed += int(failed.sum())
+    assert n_live and n_failed, "the stores must reach both outcomes"
+
+
+def test_sparse_dense_same_search_trajectory():
+    """Search is unchanged by the layout: a 6x6 job-shop solved through
+    `Solver.solve` on the dense and on the sparse Cumulative tile, with
+    8 lanes and a budget of one chunk of supersteps, ends with the same
+    incumbent after the same supersteps, sweeps and decomposition."""
+    m, _ = jobshop.build_model(jobshop.generate(6, n_machines=6, seed=4))
+    dn, sp = _compile_pair(m)
+    cfg = solver.SolveConfig(n_lanes=8, eps_target=16, chunk=64,
+                             max_supersteps=64)
+    rd, rs = (solver.Solver(cfg).solve(cm) for cm in (dn, sp))
+    assert rd.solution is not None
+    assert rd.objective == rs.objective
+    np.testing.assert_array_equal(np.asarray(rd.solution),
+                                  np.asarray(rs.solution))
+    for field in ("n_supersteps", "n_sweep_rounds",
+                  "n_decompose_sweep_rounds"):
+        assert getattr(rd, field) == getattr(rs, field), field
+    assert rd.n_supersteps == 64
 
 
 def test_sparse_dense_fixpoint_parity_all_backends():

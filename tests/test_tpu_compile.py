@@ -11,7 +11,9 @@ import: only one process may load the TPU library at a time, and with
 several test workers every worker imports this file.
 """
 
+import collections
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,8 +22,8 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 
 from repro import solver
-from repro.core import api, dist_solve, eps
-from repro.core.models import ZOO, large_instance
+from repro.core import api, dist_solve, eps, fixpoint
+from repro.core.models import ZOO, jobshop, large_instance, rcpsp
 from repro.distributed.sharding import dist_solve_specs
 from repro.kernels.fixpoint_kernel import MOSAIC_REFUSAL, fixpoint_pallas
 
@@ -137,6 +139,82 @@ def test_optional_members_runner_compiles_for_one_chip(one_chip):
     compiled = fn.lower(_shapes(cm, one_chip), pool, pool,
                         _shapes(carry, one_chip)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**30
+
+
+# Operations per StableHLO op kind in the lowered programs of a
+# dense-layout RCPSP (30 jobs, 4 resources, the prove preset: 64 lanes, a
+# pool of 256): a change to another tile must leave these programs alone.
+DENSE_RUN_CHUNK_OPS = {
+    "add": 37, "and": 66, "broadcast_in_dim": 267, "compare": 82,
+    "concatenate": 3, "constant": 159, "convert": 16, "divide": 1,
+    "gather": 10, "iota": 7, "maximum": 12, "minimum": 16, "multiply": 7,
+    "negate": 13, "not": 15, "or": 23, "reduce": 36, "reduce_window": 2,
+    "remainder": 1, "reshape": 24, "return": 9, "scatter": 2, "select": 45,
+    "sign": 2, "slice": 7, "subtract": 11, "while": 2}
+DENSE_SPLIT_LOOP_OPS = {
+    "add": 51, "and": 33, "broadcast_in_dim": 227, "case": 1, "compare": 86,
+    "concatenate": 7, "constant": 171, "convert": 12, "divide": 1,
+    "dynamic_slice": 4, "gather": 13, "iota": 5, "maximum": 13,
+    "minimum": 9, "multiply": 6, "negate": 12, "not": 4, "or": 16,
+    "reduce": 33, "reduce_window": 1, "remainder": 1, "reshape": 21,
+    "return": 21, "scatter": 11, "select": 54, "sign": 2, "slice": 4,
+    "subtract": 9, "while": 2}
+
+
+def _op_counts(text):
+    """Operations per StableHLO op kind in a lowered module's text."""
+    ops = re.findall(r"(?<![#!\w.])stablehlo\.([a-z_]+)", text)
+    return dict(collections.Counter(ops))
+
+
+def test_dense_layout_programs_keep_their_operations(one_chip):
+    """(f) The chunk runner and the split loop of a dense-layout model,
+    lowered for one chip, count the recorded operations per kind."""
+    cm = rcpsp.build_model(rcpsp.generate(30, n_resources=4, seed=0))[0] \
+        .compile(bank_layout="dense")
+    cfg = solver.SolveConfig.preset("prove", backend="gather")
+    opts = cfg.search_options()
+    fn = jax.jit(lambda cm, sl, su, c: api._run_chunk(
+        opts, cfg.stop_on_first, cfg.chunk, (), cm, sl, su, c))
+    pool = jax.ShapeDtypeStruct((256, cm.n_vars), cm.jdtype,
+                                sharding=one_chip)
+    carry = jax.eval_shape(lambda: api._init_carry(cm, cfg.n_lanes, opts))
+    text = fn.lower(_shapes(cm, one_chip), pool, pool,
+                    _shapes(carry, one_chip)).as_text()
+    assert _op_counts(text) == DENSE_RUN_CHUNK_OPS
+    split = jax.jit(eps.split_program(256, cfg.var_strategy,
+                                      cfg.val_strategy))
+    root = jax.ShapeDtypeStruct((cm.n_vars,), cm.jdtype, sharding=one_chip)
+    text = split.lower(_shapes(cm, one_chip), root, root).as_text()
+    assert _op_counts(text) == DENSE_SPLIT_LOOP_OPS
+
+
+def _scan_lengths(jaxpr):
+    """Trip counts of every scan in a jaxpr, sub-jaxprs included."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            out.append(eqn.params["length"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out += _scan_lengths(sub)
+    return out
+
+
+def test_sparse_cumulative_loops_span_one_row():
+    """(g) On a Taillard-shaped job-shop (15 x 15, the sparse Cumulative
+    tile) the sweep's loops run 2T steps, T the widest row's block, and
+    none runs over all 2M events of the bank."""
+    m, _ = jobshop.build_model(jobshop.generate(15, n_machines=15, seed=0,
+                                                max_duration=99))
+    cm = m.compile()
+    assert cm.cu_layout == "sparse" and cm.cu_width == 16
+    n_members = sum(len(cu.starts) for cu in m.cumulatives)
+    store = jnp.broadcast_to(cm.lb0, (64, cm.n_vars))
+    lengths = _scan_lengths(jax.make_jaxpr(
+        lambda lb, ub: fixpoint.sweep_batch(cm, lb, ub))(
+            store, jnp.broadcast_to(cm.ub0, store.shape)).jaxpr)
+    assert lengths == [2 * cm.cu_width]
+    assert max(lengths) < 2 * n_members
 
 
 @pytest.mark.xfail(strict=True, raises=NotImplementedError,
